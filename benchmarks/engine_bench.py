@@ -291,6 +291,9 @@ def main() -> None:
                          "(default: <--out>/profile)")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     cfg = bench_config(args.tiny)
     gc_cfg = gc_pressure_config(args.tiny)
     cc_cfg = channel_contention_config(args.tiny)

@@ -49,6 +49,9 @@ def main() -> None:
     args = ap.parse_args()
 
     from benchmarks import paper_figs, sweep_bench, tiered_kv
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     q = args.quick
     sections = [

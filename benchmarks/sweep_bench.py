@@ -393,7 +393,10 @@ def main() -> None:
 
     import jax  # after the XLA_FLAGS mutation above
 
+    from repro.compile_cache import enable_compile_cache
     from repro.experiments import sweep
+
+    enable_compile_cache()
 
     n_devices = (
         len(jax.devices()) if args.devices in (None, "all")

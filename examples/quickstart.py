@@ -10,6 +10,7 @@ IOPS and usable-capacity loss.
 
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from repro.ssdsim import engine, geometry, workload
 
 
@@ -19,6 +20,7 @@ def main():
     ap.add_argument("--zipf", type=float, default=1.2)
     ap.add_argument("--stage", default="middle", choices=["young", "middle", "old"])
     args = ap.parse_args()
+    enable_compile_cache()
 
     pe = {"young": 166, "middle": 500, "old": 833}[args.stage]
     print(f"== RARO quickstart: zipf {args.zipf}, {args.stage} stage "

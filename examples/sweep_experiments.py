@@ -34,6 +34,7 @@ def main():
 
     fake_host_devices(args.fake_devices)
 
+    from repro.compile_cache import enable_compile_cache
     from repro.experiments import registry, sweep
     from repro.ssdsim import geometry
 
@@ -43,6 +44,7 @@ def main():
     if args.scenario not in registry.names():
         ap.error(f"unknown scenario {args.scenario!r}; have {registry.names()}")
 
+    enable_compile_cache()
     spec = sweep.SweepSpec(
         scenario=args.scenario,
         n_requests=args.requests,

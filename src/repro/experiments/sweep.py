@@ -40,7 +40,6 @@ from pathlib import Path
 import jax
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -229,20 +228,19 @@ def _sweep_sharded_jit(cfg: geometry.SimConfig, lpns, ops, has_writes: bool,
     are bitwise identical to the single-device path. No collectives — runs
     are independent, making the shard axis embarrassingly parallel."""
     spec = P(_MESH_AXIS)
-    # check_rep=False: nothing here is replicated and there are no
-    # collectives, but the checker mis-types the engine's pressure-gated
-    # lax.cond branches (jax 0.4.x) — disabling it changes nothing else
+    # check_vma=False: every value is per-device (no collectives, nothing
+    # replicated), so there is nothing for the varying-axes check to prove
     if arrival_ms is None:
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda l, o, k: _run_batch(cfg, has_writes, l, o, k),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_rep=False,
+            check_vma=False,
         )
         return fn(lpns, ops, knobs)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda l, o, k, a: _run_batch(cfg, has_writes, l, o, k, a),
         mesh=mesh, in_specs=(spec, spec, spec, spec), out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(lpns, ops, knobs, arrival_ms)
 

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -36,7 +38,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     q = q_ref[0].astype(jnp.float32) * scale  # (BQ, D)
     k = k_ref[0].astype(jnp.float32)  # (BK, D)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (BQ, BK)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)  # (BQ, BK)
 
     k_pos = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
     mask = k_pos < sk_valid  # tail padding
@@ -50,9 +53,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     p = jnp.exp(s - m_new[:, None])
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
+    # the MXU accumulates in f32 whatever the operand dtype
     acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot(
-        p.astype(v_ref.dtype), v_ref[0]
-    ).astype(jnp.float32)
+        p.astype(v_ref.dtype), v_ref[0], preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(kj == n_k - 1)
@@ -62,7 +65,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 def flash_attention_fwd(q, k, v, *, sk_valid: int | None = None,
                         causal: bool = True, block_q: int = 128,
-                        block_k: int = 128, interpret: bool = True):
+                        block_k: int = 128, interpret: bool | None = None):
     """q: (BH, Sq, D); k, v: (BH_kv, Sk, D) with BH = B*H, BH_kv = B*Hk and
     the GQA group g = BH // BH_kv applied per batch entry. Sq, Sk must be
     pre-padded to block multiples by the ops wrapper; ``sk_valid`` masking
@@ -95,5 +98,5 @@ def flash_attention_fwd(q, k, v, *, sk_valid: int | None = None,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -29,7 +29,7 @@ def _pad_to(x, axis, mult):
 
 @partial(jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, interpret: bool | None = None):
     """q: (B, Sq, H, D); k, v: (B, Sk, Hk, D) -> (B, Sq, H, D)."""
     b, sq, h, d = q.shape
     _, sk, hk, _ = k.shape

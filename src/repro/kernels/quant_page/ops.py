@@ -10,6 +10,5 @@ from repro.kernels.quant_page.quant_page import quantize_pages
 
 
 @partial(jax.jit, static_argnames=("tier", "interpret"))
-def quant_pages(x, *, tier: int, interpret: bool = True):
-    q, s, e = quantize_pages(x, tier=tier, interpret=interpret)
-    return q, s, e[:, 0]
+def quant_pages(x, *, tier: int, interpret: bool | None = None):
+    return quantize_pages(x, tier=tier, interpret=interpret)

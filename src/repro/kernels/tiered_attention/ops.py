@@ -49,7 +49,7 @@ def combine_partials(parts):
 
 @partial(jax.jit, static_argnames=("cfg", "interpret"))
 def tiered_decode_attention(q, cache: paged.TieredKV, cfg: paged.CacheConfig,
-                            *, interpret: bool = True):
+                            *, interpret: bool | None = None):
     """q: (B, H, D) -> (out (B,H,D), page_mass (B, MaxP)).
 
     page_mass[b, j] = attention probability mass on logical page j (mean

@@ -1,7 +1,8 @@
 """Batched serving driver with the RARO-tiered KV cache.
 
 Serves a small dense LM: prefill a batch of prompts, decode with the
-tiered paged cache (Pallas tiered_attention in interpret mode on CPU),
+tiered paged cache (Pallas tiered_attention, compiled on a TPU and
+interpreted elsewhere),
 running the RARO controller between steps. Reports throughput, tier
 occupancy / HBM bytes, and output-quality drift vs an all-bf16 cache —
 the serving analogue of the paper's IOPS-vs-capacity trade.
@@ -29,6 +30,15 @@ def serve_cfg(vocab=512, d_model=128, n_layers=4, n_heads=4, n_kv=2):
     return ModelConfig(arch="serve-demo", family="dense", n_layers=n_layers,
                        d_model=d_model, n_heads=n_heads, n_kv_heads=n_kv,
                        d_ff=256, vocab=vocab, dtype=jnp.float32, remat=False)
+
+
+def serve_cache_cfg(cfg, steps, batch):
+    """The tiered KV cache a ``steps``-token decode of ``batch`` sequences
+    runs on: 8-token pages in small bf16 / int8 pools over a large int4 one."""
+    return paged.CacheConfig(n_seqs=batch, max_pages=max(steps // 8 + 2, 4),
+                             page_size=8, n_kv_heads=cfg.n_kv_heads,
+                             head_dim=cfg.head_dim, pool_pages=(8, 16, 256),
+                             migrate_per_step=4)
 
 
 def tiered_decode_step(params, caches, cache_cfg, rcfg, tokens, pos, cfg):
@@ -61,9 +71,7 @@ def run(steps=64, batch=4, raro_enabled=True, seed=0, cfg=None, params=None,
         params = base.materialize(api.specs(), jax.random.PRNGKey(seed), jnp.float32)
 
     hk, dh = cfg.n_kv_heads, cfg.head_dim
-    ccfg = paged.CacheConfig(n_seqs=batch, max_pages=max(steps // 8 + 2, 4),
-                             page_size=8, n_kv_heads=hk, head_dim=dh,
-                             pool_pages=(8, 16, 256), migrate_per_step=4)
+    ccfg = serve_cache_cfg(cfg, steps, batch)
     rcfg = tiers.RAROConfig(enabled=raro_enabled)
     caches = [paged.init(ccfg, jnp.float32) for _ in range(cfg.n_layers)]
 

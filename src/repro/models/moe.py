@@ -53,7 +53,6 @@ def capacity(cfg: ModelConfig, n_tokens: int) -> int:
 # ---------------------------------------------------------------------------
 def moe_apply_ep(p, x, cfg: ModelConfig, mesh):
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     tp = mesh.shape["model"]
     dp_axes = tuple(a for a in mesh.axis_names if a != "model")
@@ -145,8 +144,8 @@ def moe_apply_ep(p, x, cfg: ModelConfig, mesh):
         pspec_params["shared"] = jax.tree_util.tree_map(
             lambda _: P(None, None), p["shared"])
     x_spec = P(dp_axes, "model", None)
-    fn = shard_map(local, mesh=mesh, in_specs=(pspec_params, x_spec),
-                   out_specs=(x_spec, P()))
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(pspec_params, x_spec),
+                       out_specs=(x_spec, P()), check_vma=False)
     return fn(p, x)
 
 

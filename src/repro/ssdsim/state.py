@@ -153,9 +153,11 @@ def init_state(cfg: geometry.SimConfig, initial_pe=None,
     n_full = L // spb  # fully used blocks
     rem = L - n_full * spb
 
-    lpn = jnp.arange(L, dtype=jnp.int32)
-    l2p = lpn  # block i//spb, offset i%spb -> slot == lpn
-    p2l = jnp.full((S,), -1, jnp.int32).at[lpn].set(lpn)
+    l2p = jnp.arange(L, dtype=jnp.int32)  # block i//spb, offset i%spb -> slot == lpn
+    # the inverse of that identity map, built without a scatter: the TPU
+    # compiler aborts on a scatter whose indices are an iota
+    slot = jnp.arange(S, dtype=jnp.int32)
+    p2l = jnp.where(slot < L, slot, -1)
 
     blk = jnp.arange(B, dtype=jnp.int32)
     used_full = blk < n_full
