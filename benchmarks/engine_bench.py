@@ -212,36 +212,7 @@ def _sections(tiny: bool, n_requests: int):
     }
 
 
-class _profiler:
-    """``jax.profiler.trace`` around the timed section when ``--profile``
-    asks for it; a no-op otherwise. Profiling support varies by backend and
-    jax build, so failure to start downgrades to a warning — the benchmark
-    numbers must never depend on the profiler being available."""
-
-    def __init__(self, profile_dir, section):
-        self.dir = (str(Path(profile_dir) / section) if profile_dir else None)
-        self.active = False
-
-    def __enter__(self):
-        if self.dir:
-            try:
-                jax.profiler.start_trace(self.dir)
-                self.active = True
-            except Exception as e:  # unsupported backend/build
-                print(f"# profiler unavailable, continuing unprofiled: {e}")
-        return self
-
-    def __exit__(self, *exc):
-        if self.active:
-            try:
-                jax.profiler.stop_trace()
-                print(f"# wrote profiler trace to {self.dir}")
-            except Exception as e:
-                print(f"# profiler stop failed: {e}")
-        return False
-
-
-def bench_engine(tiny: bool, n_requests: int, repeats: int, profile_dir=None):
+def bench_engine(tiny: bool, n_requests: int, repeats: int):
     """Yield (name, value, unit) rows; compile time via AOT lower/compile so
     the steady-state timing loop never pays tracing cost."""
     from repro.ssdsim import engine
@@ -264,11 +235,10 @@ def bench_engine(tiny: bool, n_requests: int, repeats: int, profile_dir=None):
         compile_s = time.perf_counter() - t0
 
         jax.block_until_ready(run())  # warm-up / page in
-        with _profiler(profile_dir, wl):
-            t0 = time.perf_counter()
-            for _ in range(repeats):
-                jax.block_until_ready(run())
-            dt = (time.perf_counter() - t0) / repeats
+        t0 = time.perf_counter()
+        for _ in range(repeats):
+            jax.block_until_ready(run())
+        dt = (time.perf_counter() - t0) / repeats
 
         yield f"engine/{wl}/compile_s", compile_s, "s"
         yield f"engine/{wl}/ms_per_chunk", dt / n_chunks * 1e3, "ms"
@@ -283,12 +253,6 @@ def main() -> None:
     ap.add_argument("--requests", type=int, default=None)
     ap.add_argument("--out", default=".", metavar="DIR",
                     help="directory for the BENCH_engine.json artifact")
-    ap.add_argument("--profile", action="store_true",
-                    help="wrap the timed loop in jax.profiler.trace "
-                         "(ignored with a warning when unsupported)")
-    ap.add_argument("--profile-dir", default=None, metavar="DIR",
-                    help="profiler artifact directory "
-                         "(default: <--out>/profile)")
     args = ap.parse_args()
 
     from repro.compile_cache import enable_compile_cache
@@ -299,13 +263,9 @@ def main() -> None:
     cc_cfg = channel_contention_config(args.tiny)
     n_requests = args.requests or (4 * cfg.chunk if args.tiny else 40 * cfg.chunk)
 
-    profile_dir = None
-    if args.profile:
-        profile_dir = args.profile_dir or str(Path(args.out) / "profile")
-
     rows = []
     print("name,value,unit")
-    for row in bench_engine(args.tiny, n_requests, args.repeats, profile_dir):
+    for row in bench_engine(args.tiny, n_requests, args.repeats):
         rows.append(list(row))
         n, v, u = row
         print(f"{n},{v:.4f},{u}", flush=True)

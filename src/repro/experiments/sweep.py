@@ -383,10 +383,11 @@ def run_sweep(spec: SweepSpec, threads: int = 4, verbose: bool = False,
     # traces depend only on (scenario, seed): build each once, share across
     # policies/knobs
     traces: dict[int, dict] = {}
-    for seed in spec.seeds:
-        traces[seed] = registry.build(
-            spec.scenario, spec.base, spec.n_requests, seed=seed, **kw
-        )
+    with jax.profiler.TraceAnnotation("repro.sweep.build"):
+        for seed in spec.seeds:
+            traces[seed] = registry.build(
+                spec.scenario, spec.base, spec.n_requests, seed=seed, **kw
+            )
     has_writes = bool(any((t["op"] == engine.OP_WRITE).any() for t in traces.values()))
     open_loop = all("arrival_ms" in t for t in traces.values())
     if spec.arrival_scale != (1.0,) and not open_loop:
@@ -427,80 +428,82 @@ def run_sweep(spec: SweepSpec, threads: int = 4, verbose: bool = False,
             # with dummy replicas of the last run so the run axis divides
             # the mesh; the pads are dropped on the host below, never
             # summarized
-            n_pad = (-len(group)) % len(devs) if devs is not None else 0
-            padded = group + [group[-1]] * n_pad
-            # stacked on the host (numpy): the vmap path lets jit move them
-            # to the default device as before, the sharded path transfers
-            # each array exactly once, straight to its run-sharded layout
-            lpns = np.stack([np.asarray(traces[r.seed]["lpn"], np.int32) for r in padded])
-            ops = np.stack([np.asarray(traces[r.seed]["op"], np.int32) for r in padded])
-            arr = (
-                np.stack([np.asarray(traces[r.seed]["arrival_ms"], np.float32)
-                          for r in padded])
-                if open_loop else None
-            )
-            knobs = policies.RunKnobs(
-                r1=np.asarray([r.r1 for r in padded], np.int32),
-                r2_override=np.asarray([r.r2_override for r in padded], np.int32),
-                initial_pe=np.asarray([r.initial_pe for r in padded], np.int32),
-                arrival_scale=(
-                    np.asarray([r.arrival_scale for r in padded], np.float32)
+            with jax.profiler.TraceAnnotation("repro.sweep.stack"):
+                n_pad = (-len(group)) % len(devs) if devs is not None else 0
+                padded = group + [group[-1]] * n_pad
+                # stacked on the host (numpy): the vmap path lets jit move them
+                # to the default device as before, the sharded path transfers
+                # each array exactly once, straight to its run-sharded layout
+                lpns = np.stack([np.asarray(traces[r.seed]["lpn"], np.int32) for r in padded])
+                ops = np.stack([np.asarray(traces[r.seed]["op"], np.int32) for r in padded])
+                arr = (
+                    np.stack([np.asarray(traces[r.seed]["arrival_ms"], np.float32)
+                              for r in padded])
                     if open_loop else None
-                ),
-                prog_fail_rate=(
-                    np.asarray([r.prog_fail_rate for r in padded], np.float32)
-                    if faults_on else None
-                ),
-                erase_fail_rate=(
-                    np.asarray([r.erase_fail_rate for r in padded], np.float32)
-                    if faults_on else None
-                ),
-                max_read_retries=(
-                    np.asarray([r.max_read_retries for r in padded], np.int32)
-                    if faults_on else None
-                ),
-                fault_seed=(
-                    np.asarray([r.fault_seed for r in padded], np.int32)
-                    if faults_on else None
-                ),
-                read_fail_rate=(
-                    np.asarray([r.read_fail_rate for r in padded], np.float32)
-                    if faults_on else None
-                ),
-                fault_wear_slope=(
-                    np.asarray([r.fault_wear_slope for r in padded],
-                               np.float32)
-                    if faults_on else None
-                ),
-                parity_rebuild=(
-                    np.asarray([int(r.parity_rebuild) for r in padded],
-                               np.int32)
-                    if faults_on else None
-                ),
-                spare_blocks=(
-                    np.asarray([r.spare_blocks for r in padded], np.int32)
-                    if faults_on else None
-                ),
-                gc_objective=(
-                    np.asarray(
-                        [reclaim.GC_OBJECTIVE_CODES[r.gc_objective]
-                         for r in padded], np.int32)
-                    if spec.gc_objective != ("min_valid",) else None
-                ),
-            )
+                )
+                knobs = policies.RunKnobs(
+                    r1=np.asarray([r.r1 for r in padded], np.int32),
+                    r2_override=np.asarray([r.r2_override for r in padded], np.int32),
+                    initial_pe=np.asarray([r.initial_pe for r in padded], np.int32),
+                    arrival_scale=(
+                        np.asarray([r.arrival_scale for r in padded], np.float32)
+                        if open_loop else None
+                    ),
+                    prog_fail_rate=(
+                        np.asarray([r.prog_fail_rate for r in padded], np.float32)
+                        if faults_on else None
+                    ),
+                    erase_fail_rate=(
+                        np.asarray([r.erase_fail_rate for r in padded], np.float32)
+                        if faults_on else None
+                    ),
+                    max_read_retries=(
+                        np.asarray([r.max_read_retries for r in padded], np.int32)
+                        if faults_on else None
+                    ),
+                    fault_seed=(
+                        np.asarray([r.fault_seed for r in padded], np.int32)
+                        if faults_on else None
+                    ),
+                    read_fail_rate=(
+                        np.asarray([r.read_fail_rate for r in padded], np.float32)
+                        if faults_on else None
+                    ),
+                    fault_wear_slope=(
+                        np.asarray([r.fault_wear_slope for r in padded],
+                                   np.float32)
+                        if faults_on else None
+                    ),
+                    parity_rebuild=(
+                        np.asarray([int(r.parity_rebuild) for r in padded],
+                                   np.int32)
+                        if faults_on else None
+                    ),
+                    spare_blocks=(
+                        np.asarray([r.spare_blocks for r in padded], np.int32)
+                        if faults_on else None
+                    ),
+                    gc_objective=(
+                        np.asarray(
+                            [reclaim.GC_OBJECTIVE_CODES[r.gc_objective]
+                             for r in padded], np.int32)
+                        if spec.gc_objective != ("min_valid",) else None
+                    ),
+                )
             if verbose:
                 where = (f"sharded over {len(devs)} devices"
                          f" (+{n_pad} pad)" if devs is not None else "one device")
                 print(f"# sweep group policy={geometry.POLICY_NAMES[pol]}: "
                       f"{len(group)} runs in one jit, {where}", flush=True)
-            if mesh is None:
-                return _sweep_jit(cfg, lpns, ops, has_writes, knobs, arr)
-            place = lambda x: jax.device_put(x, run_sharding)  # noqa: E731
-            lpns, ops = place(lpns), place(ops)
-            arr = place(arr) if arr is not None else None
-            knobs = jax.tree_util.tree_map(place, knobs)
-            return _sweep_sharded_jit(cfg, lpns, ops, has_writes, knobs,
-                                      arr, mesh)
+            with jax.profiler.TraceAnnotation("repro.sweep.dispatch"):
+                if mesh is None:
+                    return _sweep_jit(cfg, lpns, ops, has_writes, knobs, arr)
+                place = lambda x: jax.device_put(x, run_sharding)  # noqa: E731
+                lpns, ops = place(lpns), place(ops)
+                arr = place(arr) if arr is not None else None
+                knobs = jax.tree_util.tree_map(place, knobs)
+                return _sweep_sharded_jit(cfg, lpns, ops, has_writes, knobs,
+                                          arr, mesh)
 
         try:
             states = _dispatch()
@@ -529,7 +532,8 @@ def run_sweep(spec: SweepSpec, threads: int = 4, verbose: bool = False,
             try:
                 if states is None:  # prior dispatch/fetch failed -> redo
                     states = redispatch()
-                host = jax.device_get(states)  # blocks on this group only
+                with jax.profiler.TraceAnnotation("repro.sweep.fetch"):
+                    host = jax.device_get(states)  # blocks on this group only
                 break
             except Exception as e:  # one poisoned group must not lose the grid
                 last_err = e
@@ -545,30 +549,31 @@ def run_sweep(spec: SweepSpec, threads: int = 4, verbose: bool = False,
         if host is None:
             failed.append((name, last_err))
             continue
-        group_results = []
-        for i, r in enumerate(group):  # pads (indices >= len(group)) dropped
-            m = engine.summarize(_take_run(host, i), cfg, threads=threads)
-            m["run"] = dict(
-                scenario=r.scenario,
-                policy=geometry.POLICY_NAMES[r.policy],
-                initial_pe=r.initial_pe,
-                seed=r.seed,
-                r1=r.r1,
-                r2_override=r.r2_override,
-                arrival_scale=r.arrival_scale,
-                prog_fail_rate=r.prog_fail_rate,
-                erase_fail_rate=r.erase_fail_rate,
-                max_read_retries=r.max_read_retries,
-                fault_seed=r.fault_seed,
-                read_fail_rate=r.read_fail_rate,
-                fault_wear_slope=r.fault_wear_slope,
-                parity_rebuild=r.parity_rebuild,
-                spare_blocks=r.spare_blocks,
-                gc_objective=r.gc_objective,
-                n_requests=spec.n_requests,
-                tag=r.tag(),
-            )
-            group_results.append(m)
+        with jax.profiler.TraceAnnotation("repro.sweep.summarize"):
+            group_results = []
+            for i, r in enumerate(group):  # pads (indices >= len(group)) dropped
+                m = engine.summarize(_take_run(host, i), cfg, threads=threads)
+                m["run"] = dict(
+                    scenario=r.scenario,
+                    policy=geometry.POLICY_NAMES[r.policy],
+                    initial_pe=r.initial_pe,
+                    seed=r.seed,
+                    r1=r.r1,
+                    r2_override=r.r2_override,
+                    arrival_scale=r.arrival_scale,
+                    prog_fail_rate=r.prog_fail_rate,
+                    erase_fail_rate=r.erase_fail_rate,
+                    max_read_retries=r.max_read_retries,
+                    fault_seed=r.fault_seed,
+                    read_fail_rate=r.read_fail_rate,
+                    fault_wear_slope=r.fault_wear_slope,
+                    parity_rebuild=r.parity_rebuild,
+                    spare_blocks=r.spare_blocks,
+                    gc_objective=r.gc_objective,
+                    n_requests=spec.n_requests,
+                    tag=r.tag(),
+                )
+                group_results.append(m)
         if resume_dir is not None:
             _write_group_checkpoint(
                 _group_ckpt_path(resume_dir, spec, group[0].policy),

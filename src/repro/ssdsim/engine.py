@@ -428,195 +428,198 @@ def step_chunk(s: st.SSDState, req, cfg: geometry.SimConfig, has_writes: bool,
     (sweep runner); ``None`` reads them from ``cfg`` as before."""
     lpns, ops = req[0], req[1]
     arrival = req[2] if len(req) == 3 else None
-    is_read = ops == OP_READ
-    fp = flt.params_for(cfg, knobs)  # None = no fault ops traced at all
-    # chunk-start write counters: the windowed WAF series is the per-chunk
-    # delta of (host pages, relocated pages), not the cumulative ratio
-    w_c0, r_c0 = s.n_writes, s.n_reloc_pages
+    with jax.named_scope("step_read"):
+        is_read = ops == OP_READ
+        fp = flt.params_for(cfg, knobs)  # None = no fault ops traced at all
+        # chunk-start write counters: the windowed WAF series is the per-chunk
+        # delta of (host pages, relocated pages), not the cumulative ratio
+        w_c0, r_c0 = s.n_writes, s.n_reloc_pages
 
-    # ---------------- reads (vectorized) ----------------
-    slot, blk, mode, retries, ok = lookup(s, lpns, cfg)
-    rd = is_read & ok
-    svc_us = jnp.where(rd, retry.read_latency_us(mode, retries), 0.0)
-    if fp is not None:
-        # uncorrectable reads (DESIGN.md §2D): over-budget retry estimates
-        # do not decode on-chip — burn the budget, then pay the recovery
-        # penalty (flat ECC soft-decode, or a die-parity rebuild when
-        # armed). On top of the budget path every read draws a wear-scaled
-        # probabilistic uncorrectable (``read_fail_rate``). retries
-        # collapses to the budget actually spent only for budget-overs so
-        # the retry stats stay truthful; a probabilistic uncorrectable
-        # decoded in its estimated retries before the late ECC failure.
-        mrr = fp.max_read_retries
-        pe_r = s.block_pe[blk]
-        rated_r = modes.PE_LIMIT[mode]
-        over = rd & (mrr >= 0) & (retries > mrr)
-        uncorr = over | (rd & flt.read_fails(fp, slot, pe_r, rated_r))
-        retries = jnp.where(over, jnp.maximum(mrr, 0), retries)
-        rec_us = flt.recovery_us(fp, mode, cfg)
-        svc_us = jnp.where(
-            rd,
-            retry.read_latency_us(mode, retries)
-            + jnp.where(uncorr, rec_us, 0.0),
-            0.0,
-        )
-        # per-lane rebuild mass: the recovery time of uncorrectable lanes
-        # recovered via die-parity (split out of the retry component in the
-        # obs attribution so rebuild cost is visible on its own). A
-        # single-die device has no stripe peers, so parity can never
-        # reconstruct there — recovery_us already fell back to the flat
-        # penalty and the rebuild lane must stay empty
-        if cfg.n_dies > 1:
-            is_rb = uncorr & (fp.parity_rebuild > 0)
-        else:
-            is_rb = jnp.zeros_like(uncorr)
-        rb_lane_us = jnp.where(is_rb, rec_us, 0.0)
-    else:
-        uncorr = None
-        rb_lane_us = jnp.zeros_like(svc_us)
-    xfer_us = jnp.where(rd, cfg.transfer_us, 0.0)
-    die = cfg.die_of_block(blk)
-    chan = cfg.channel_of_die(die)
-
-    # ---------------- open-loop queueing (DESIGN.md §2C) ----------------
-    if arrival is not None:
-        scale = (
-            jnp.float32(1.0)
-            if knobs is None or knobs.arrival_scale is None
-            else knobs.arrival_scale.astype(jnp.float32)
-        )
-        t_arr = arrival / scale  # scale multiplies the offered rate
-        wv = (ops == OP_WRITE) & (lpns >= 0)
-        active = rd | wv
-        q_die = jnp.where(rd, die, jnp.maximum(lpns, 0) % cfg.n_dies).astype(jnp.int32)
-        # die occupancy: sense+retries for reads, page program for writes —
-        # the same terms the closed-loop model books into die_busy_ms.
-        occ_us = jnp.where(rd, svc_us, modes.WRITE_LATENCY_US[modes.QLC])
-        if cfg.chan_model == "lattice":
-            # two-resource tandem: sense/program queues on the die, then the
-            # page transfer queues on the die's channel bus
-            die_dep, chan_dep, die_avail, chan_avail = _tandem_departures(
-                s.die_avail_ms, s.chan_avail_ms, t_arr,
-                jnp.where(active, occ_us, 0.0) / 1000.0,
-                jnp.where(active, cfg.transfer_us, 0.0) / 1000.0,
-                q_die, cfg.channel_of_die(q_die), rd, active,
-                cfg.n_dies, cfg.n_channels,
-            )
-            dep_ms = jnp.where(rd, chan_dep, die_dep)
-            sojourn_us = jnp.where(
+        # ---------------- reads (vectorized) ----------------
+        slot, blk, mode, retries, ok = lookup(s, lpns, cfg)
+        rd = is_read & ok
+        svc_us = jnp.where(rd, retry.read_latency_us(mode, retries), 0.0)
+        if fp is not None:
+            # uncorrectable reads (DESIGN.md §2D): over-budget retry estimates
+            # do not decode on-chip — burn the budget, then pay the recovery
+            # penalty (flat ECC soft-decode, or a die-parity rebuild when
+            # armed). On top of the budget path every read draws a wear-scaled
+            # probabilistic uncorrectable (``read_fail_rate``). retries
+            # collapses to the budget actually spent only for budget-overs so
+            # the retry stats stay truthful; a probabilistic uncorrectable
+            # decoded in its estimated retries before the late ECC failure.
+            mrr = fp.max_read_retries
+            pe_r = s.block_pe[blk]
+            rated_r = modes.PE_LIMIT[mode]
+            over = rd & (mrr >= 0) & (retries > mrr)
+            uncorr = over | (rd & flt.read_fails(fp, slot, pe_r, rated_r))
+            retries = jnp.where(over, jnp.maximum(mrr, 0), retries)
+            rec_us = flt.recovery_us(fp, mode, cfg)
+            svc_us = jnp.where(
                 rd,
-                (chan_dep - t_arr) * 1000.0,
-                (die_dep - t_arr) * 1000.0 + cfg.transfer_us,
-            )
-            queue_us = jnp.maximum((die_dep - t_arr) * 1000.0 - occ_us, 0.0)
-            chanw_us = jnp.where(
-                rd,
-                jnp.maximum((chan_dep - die_dep) * 1000.0 - cfg.transfer_us,
-                            0.0),
+                retry.read_latency_us(mode, retries)
+                + jnp.where(uncorr, rec_us, 0.0),
                 0.0,
             )
+            # per-lane rebuild mass: the recovery time of uncorrectable lanes
+            # recovered via die-parity (split out of the retry component in the
+            # obs attribution so rebuild cost is visible on its own). A
+            # single-die device has no stripe peers, so parity can never
+            # reconstruct there — recovery_us already fell back to the flat
+            # penalty and the rebuild lane must stay empty
+            if cfg.n_dies > 1:
+                is_rb = uncorr & (fp.parity_rebuild > 0)
+            else:
+                is_rb = jnp.zeros_like(uncorr)
+            rb_lane_us = jnp.where(is_rb, rec_us, 0.0)
         else:
-            # legacy: channel transfer is appended to the recorded latency
-            # but does not occupy a resource (it overlaps the next sense)
-            dep_ms, die_avail = _queue_departures(
-                s.die_avail_ms, t_arr, jnp.where(active, occ_us, 0.0) / 1000.0,
-                q_die, active, cfg.n_dies,
-            )
-            chan_avail = s.chan_avail_ms
-            sojourn_us = (dep_ms - t_arr) * 1000.0 + cfg.transfer_us
-            queue_us = jnp.maximum(sojourn_us - occ_us - cfg.transfer_us, 0.0)
-            chanw_us = jnp.zeros_like(queue_us)
-        rec_lat_us = sojourn_us  # queue + sense/retry (or program) + wait + xfer
-        chunk_q = jnp.where(rd, queue_us, 0.0).sum() / 1000.0
-        chunk_chanw = jnp.where(rd, chanw_us, 0.0).sum() / 1000.0
-        chunk_svc = jnp.where(rd, rec_lat_us, 0.0).sum() / 1000.0
-        chunk_hist = telemetry.record(
-            jnp.zeros((telemetry.N_LAT_BINS,), jnp.float32), rec_lat_us, rd
-        )
-    else:
-        chunk_q = jnp.float32(0.0)
-        chunk_chanw = jnp.float32(0.0)
-        chunk_svc = (svc_us + xfer_us).sum() / 1000.0
-        chunk_hist = telemetry.record(
-            jnp.zeros((telemetry.N_LAT_BINS,), jnp.float32), svc_us + xfer_us, rd
-        )
+            uncorr = None
+            rb_lane_us = jnp.zeros_like(svc_us)
+        xfer_us = jnp.where(rd, cfg.transfer_us, 0.0)
+        die = cfg.die_of_block(blk)
+        chan = cfg.channel_of_die(die)
 
-    die_add = jax.ops.segment_sum(svc_us, die, num_segments=cfg.n_dies) / 1000.0
-    chan_add = jax.ops.segment_sum(xfer_us, chan, num_segments=cfg.n_channels) / 1000.0
-    chunk_reads = rd.sum().astype(jnp.float32)
-    chunk_retries = jnp.where(rd, retries, 0).sum().astype(jnp.float32)
-
-    s = s._replace(
-        die_busy_ms=s.die_busy_ms + die_add,
-        chan_busy_ms=s.chan_busy_ms + chan_add,
-        block_reads=s.block_reads
-        + jax.ops.segment_sum(rd.astype(jnp.int32), blk, num_segments=cfg.n_blocks),
-        svc_sum_ms=s.svc_sum_ms + chunk_svc,
-        q_sum_ms=s.q_sum_ms + chunk_q,
-        chanq_sum_ms=s.chanq_sum_ms + chunk_chanw,
-        n_reads=s.n_reads + chunk_reads,
-        n_retries=s.n_retries + chunk_retries,
-        lat_hist=s.lat_hist + chunk_hist,
-    )
-    if uncorr is not None:
-        # die-parity rebuild accounting (DESIGN.md §2D): every rebuilt lane
-        # counts; a second uncorrectable among the stripe peers during the
-        # rebuild is true data loss (the sim keeps serving the stale page —
-        # no mapping entry is harmed, only the counter records it)
-        n_rb = is_rb.sum().astype(jnp.float32)
-        if cfg.n_dies > 1:
-            loss = is_rb & flt.rebuild_second_fault(
-                fp, slot, pe_r, rated_r, cfg.n_dies - 1
-            )
-            n_dl = loss.sum().astype(jnp.float32)
-        else:
-            n_dl = jnp.float32(0.0)
-        s = s._replace(
-            n_uncorrectable=s.n_uncorrectable + uncorr.sum().astype(jnp.float32),
-            n_rebuilds=s.n_rebuilds + n_rb,
-            n_data_loss=s.n_data_loss + n_dl,
-        )
-
-    # ---------------- observability: read-path attribution ----------------
-    if obs.enabled(cfg):
-        # decompose each recorded read into queue / sense / retry / transfer
-        # components; the binning latency is exactly what lat_hist records,
-        # so the per-mode count histograms sum back to it bit for bit
-        base_us = jnp.where(rd, modes.READ_LATENCY_US[mode], 0.0)
+        # ---------------- open-loop queueing (DESIGN.md §2C) ----------------
         if arrival is not None:
-            q_us = jnp.where(rd, queue_us, 0.0)
-            cw_us = jnp.where(rd, chanw_us, 0.0)
-            t_read_ms = dep_ms  # window by each read's own departure time
-            lat_us = rec_lat_us
+            scale = (
+                jnp.float32(1.0)
+                if knobs is None or knobs.arrival_scale is None
+                else knobs.arrival_scale.astype(jnp.float32)
+            )
+            t_arr = arrival / scale  # scale multiplies the offered rate
+            wv = (ops == OP_WRITE) & (lpns >= 0)
+            active = rd | wv
+            q_die = jnp.where(rd, die, jnp.maximum(lpns, 0) % cfg.n_dies).astype(jnp.int32)
+            # die occupancy: sense+retries for reads, page program for writes —
+            # the same terms the closed-loop model books into die_busy_ms.
+            occ_us = jnp.where(rd, svc_us, modes.WRITE_LATENCY_US[modes.QLC])
+            if cfg.chan_model == "lattice":
+                # two-resource tandem: sense/program queues on the die, then the
+                # page transfer queues on the die's channel bus
+                die_dep, chan_dep, die_avail, chan_avail = _tandem_departures(
+                    s.die_avail_ms, s.chan_avail_ms, t_arr,
+                    jnp.where(active, occ_us, 0.0) / 1000.0,
+                    jnp.where(active, cfg.transfer_us, 0.0) / 1000.0,
+                    q_die, cfg.channel_of_die(q_die), rd, active,
+                    cfg.n_dies, cfg.n_channels,
+                )
+                dep_ms = jnp.where(rd, chan_dep, die_dep)
+                sojourn_us = jnp.where(
+                    rd,
+                    (chan_dep - t_arr) * 1000.0,
+                    (die_dep - t_arr) * 1000.0 + cfg.transfer_us,
+                )
+                queue_us = jnp.maximum((die_dep - t_arr) * 1000.0 - occ_us, 0.0)
+                chanw_us = jnp.where(
+                    rd,
+                    jnp.maximum((chan_dep - die_dep) * 1000.0 - cfg.transfer_us,
+                                0.0),
+                    0.0,
+                )
+            else:
+                # legacy: channel transfer is appended to the recorded latency
+                # but does not occupy a resource (it overlaps the next sense)
+                dep_ms, die_avail = _queue_departures(
+                    s.die_avail_ms, t_arr, jnp.where(active, occ_us, 0.0) / 1000.0,
+                    q_die, active, cfg.n_dies,
+                )
+                chan_avail = s.chan_avail_ms
+                sojourn_us = (dep_ms - t_arr) * 1000.0 + cfg.transfer_us
+                queue_us = jnp.maximum(sojourn_us - occ_us - cfg.transfer_us, 0.0)
+                chanw_us = jnp.zeros_like(queue_us)
+            rec_lat_us = sojourn_us  # queue + sense/retry (or program) + wait + xfer
+            chunk_q = jnp.where(rd, queue_us, 0.0).sum() / 1000.0
+            chunk_chanw = jnp.where(rd, chanw_us, 0.0).sum() / 1000.0
+            chunk_svc = jnp.where(rd, rec_lat_us, 0.0).sum() / 1000.0
+            chunk_hist = telemetry.record(
+                jnp.zeros((telemetry.N_LAT_BINS,), jnp.float32), rec_lat_us, rd
+            )
         else:
-            q_us = jnp.zeros_like(svc_us)
-            cw_us = jnp.zeros_like(svc_us)
-            t_read_ms = jnp.broadcast_to(s.clock_ms, svc_us.shape)
-            lat_us = svc_us + xfer_us
-        s = obs.record_reads(
-            s, cfg, mode=mode, rd=rd, lat_us=lat_us, queue_us=q_us,
-            sense_us=base_us, retry_us=svc_us - base_us - rb_lane_us,
-            chanw_us=cw_us, xfer_us=xfer_us, retries=retries, t_ms=t_read_ms,
-            uncorr=uncorr, rebuild_us=rb_lane_us,
+            chunk_q = jnp.float32(0.0)
+            chunk_chanw = jnp.float32(0.0)
+            chunk_svc = (svc_us + xfer_us).sum() / 1000.0
+            chunk_hist = telemetry.record(
+                jnp.zeros((telemetry.N_LAT_BINS,), jnp.float32), svc_us + xfer_us, rd
+            )
+
+        die_add = jax.ops.segment_sum(svc_us, die, num_segments=cfg.n_dies) / 1000.0
+        chan_add = jax.ops.segment_sum(xfer_us, chan, num_segments=cfg.n_channels) / 1000.0
+        chunk_reads = rd.sum().astype(jnp.float32)
+        chunk_retries = jnp.where(rd, retries, 0).sum().astype(jnp.float32)
+
+        s = s._replace(
+            die_busy_ms=s.die_busy_ms + die_add,
+            chan_busy_ms=s.chan_busy_ms + chan_add,
+            block_reads=s.block_reads
+            + jax.ops.segment_sum(rd.astype(jnp.int32), blk, num_segments=cfg.n_blocks),
+            svc_sum_ms=s.svc_sum_ms + chunk_svc,
+            q_sum_ms=s.q_sum_ms + chunk_q,
+            chanq_sum_ms=s.chanq_sum_ms + chunk_chanw,
+            n_reads=s.n_reads + chunk_reads,
+            n_retries=s.n_retries + chunk_retries,
+            lat_hist=s.lat_hist + chunk_hist,
         )
-        obs0 = (s.n_writes, s.n_conversions.sum(), s.n_erases,
-                s.n_migrated_pages, s.n_reloc_pages)
+        if uncorr is not None:
+            # die-parity rebuild accounting (DESIGN.md §2D): every rebuilt lane
+            # counts; a second uncorrectable among the stripe peers during the
+            # rebuild is true data loss (the sim keeps serving the stale page —
+            # no mapping entry is harmed, only the counter records it)
+            n_rb = is_rb.sum().astype(jnp.float32)
+            if cfg.n_dies > 1:
+                loss = is_rb & flt.rebuild_second_fault(
+                    fp, slot, pe_r, rated_r, cfg.n_dies - 1
+                )
+                n_dl = loss.sum().astype(jnp.float32)
+            else:
+                n_dl = jnp.float32(0.0)
+            s = s._replace(
+                n_uncorrectable=s.n_uncorrectable + uncorr.sum().astype(jnp.float32),
+                n_rebuilds=s.n_rebuilds + n_rb,
+                n_data_loss=s.n_data_loss + n_dl,
+            )
+
+        # ---------------- observability: read-path attribution ----------------
+        if obs.enabled(cfg):
+            # decompose each recorded read into queue / sense / retry / transfer
+            # components; the binning latency is exactly what lat_hist records,
+            # so the per-mode count histograms sum back to it bit for bit
+            base_us = jnp.where(rd, modes.READ_LATENCY_US[mode], 0.0)
+            if arrival is not None:
+                q_us = jnp.where(rd, queue_us, 0.0)
+                cw_us = jnp.where(rd, chanw_us, 0.0)
+                t_read_ms = dep_ms  # window by each read's own departure time
+                lat_us = rec_lat_us
+            else:
+                q_us = jnp.zeros_like(svc_us)
+                cw_us = jnp.zeros_like(svc_us)
+                t_read_ms = jnp.broadcast_to(s.clock_ms, svc_us.shape)
+                lat_us = svc_us + xfer_us
+            s = obs.record_reads(
+                s, cfg, mode=mode, rd=rd, lat_us=lat_us, queue_us=q_us,
+                sense_us=base_us, retry_us=svc_us - base_us - rb_lane_us,
+                chanw_us=cw_us, xfer_us=xfer_us, retries=retries, t_ms=t_read_ms,
+                uncorr=uncorr, rebuild_us=rb_lane_us,
+            )
+            obs0 = (s.n_writes, s.n_conversions.sum(), s.n_erases,
+                    s.n_migrated_pages, s.n_reloc_pages)
 
     # ---------------- heat update ----------------
-    touched = rd | (ops == OP_WRITE)
-    heat = hotness.decay_heat(s.heat, cfg.heat)
-    heat = heat.at[jnp.where(touched, lpns, cfg.n_logical)].add(1.0, mode="drop")
-    s = s._replace(heat=heat)
+    with jax.named_scope("step_heat"):
+        touched = rd | (ops == OP_WRITE)
+        heat = hotness.decay_heat(s.heat, cfg.heat)
+        heat = heat.at[jnp.where(touched, lpns, cfg.n_logical)].add(1.0, mode="drop")
+        s = s._replace(heat=heat)
 
     # ---------------- user writes ----------------
     if has_writes:
-        w_hist0 = s.w_lat_hist
-        s = write_path_batched(
-            s, lpns, ops == OP_WRITE, cfg,
-            w_lat_us=rec_lat_us if arrival is not None else None,
-            faults=fp,
-        )
-        chunk_w_hist = s.w_lat_hist - w_hist0
+        with jax.named_scope("step_write"):
+            w_hist0 = s.w_lat_hist
+            s = write_path_batched(
+                s, lpns, ops == OP_WRITE, cfg,
+                w_lat_us=rec_lat_us if arrival is not None else None,
+                faults=fp,
+            )
+            chunk_w_hist = s.w_lat_hist - w_hist0
     else:
         chunk_w_hist = jnp.zeros((telemetry.N_LAT_BINS,), jnp.float32)
 
@@ -633,138 +636,143 @@ def step_chunk(s: st.SSDState, req, cfg: geometry.SimConfig, has_writes: bool,
     # arrivals queue behind the rebuild. With ``parity_rebuild`` off (or a
     # one-die geometry) every charge is exactly 0.0 and the clocks are
     # untouched bit for bit.
-    if fp is not None and cfg.n_dies > 1:
-        rb_sense_us = jnp.where(is_rb, modes.READ_LATENCY_US[mode], 0.0)
-        own_sense = jax.ops.segment_sum(rb_sense_us, die,
-                                        num_segments=cfg.n_dies)
-        rb_die_ms = (rb_sense_us.sum() - own_sense) / 1000.0
-        n_rb_chan = jax.ops.segment_sum(
-            is_rb.astype(jnp.float32), chan, num_segments=cfg.n_channels
-        )
-        rb_chan_ms = (
-            (is_rb.sum().astype(jnp.float32) * cfg.luns_per_channel - n_rb_chan)
-            * cfg.transfer_us
-        ) / 1000.0
-        s = s._replace(
-            die_busy_ms=s.die_busy_ms + rb_die_ms,
-            chan_busy_ms=s.chan_busy_ms + rb_chan_ms,
-        )
-        if arrival is not None and cfg.chan_model == "lattice":
-            chan_avail = chan_avail + rb_chan_ms
+    with jax.named_scope("step_read"):
+        if fp is not None and cfg.n_dies > 1:
+            rb_sense_us = jnp.where(is_rb, modes.READ_LATENCY_US[mode], 0.0)
+            own_sense = jax.ops.segment_sum(rb_sense_us, die,
+                                            num_segments=cfg.n_dies)
+            rb_die_ms = (rb_sense_us.sum() - own_sense) / 1000.0
+            n_rb_chan = jax.ops.segment_sum(
+                is_rb.astype(jnp.float32), chan, num_segments=cfg.n_channels
+            )
+            rb_chan_ms = (
+                (is_rb.sum().astype(jnp.float32) * cfg.luns_per_channel - n_rb_chan)
+                * cfg.transfer_us
+            ) / 1000.0
+            s = s._replace(
+                die_busy_ms=s.die_busy_ms + rb_die_ms,
+                chan_busy_ms=s.chan_busy_ms + rb_chan_ms,
+            )
+            if arrival is not None and cfg.chan_model == "lattice":
+                chan_avail = chan_avail + rb_chan_ms
 
     # ---------------- policy: conversion migrations ----------------
     if cfg.policy != geometry.BASELINE:
-        # dedup of the chunk's read set: one int32 sort + adjacent-equal
-        # mask. Replaces jnp.unique(size=chunk) (~9x slower: it layers
-        # cumsum/scatter compaction on top of the same sort). Masked lanes
-        # sort to the top as n_logical and drop to -1; survivors stay in
-        # ascending LPN order, so heat ties in the top-k below break
-        # identically to the jnp.unique ordering. (A sort-free scatter-mark
-        # on an (L,)-sized scratch was measured slower: the per-chunk fill
-        # of the scratch dominates at real geometry.)
-        srt = jnp.sort(jnp.where(rd, lpns, cfg.n_logical))
-        dup = jnp.concatenate([jnp.zeros((1,), bool), srt[1:] == srt[:-1]])
-        uniq = jnp.where((srt >= cfg.n_logical) | dup, -1, srt)
-        slot_u, blk_u, mode_u, retr_u, ok_u = lookup(s, uniq, cfg)
-        heat_u = s.heat[jnp.maximum(uniq, 0)]
-        sel = policies.select_migrations(
-            cfg, uniq, mode_u, retr_u, heat_u, ok_u, s.block_pe[blk_u], knobs=knobs
-        )
-        for tgt in (modes.SLC, modes.TLC):
-            s = ftl.maybe_migrate_pages(s, sel[tgt], tgt, cfg, faults=fp)
+        with jax.named_scope("step_convert"):
+            # dedup of the chunk's read set: one int32 sort + adjacent-equal
+            # mask. Replaces jnp.unique(size=chunk) (~9x slower: it layers
+            # cumsum/scatter compaction on top of the same sort). Masked lanes
+            # sort to the top as n_logical and drop to -1; survivors stay in
+            # ascending LPN order, so heat ties in the top-k below break
+            # identically to the jnp.unique ordering. (A sort-free scatter-mark
+            # on an (L,)-sized scratch was measured slower: the per-chunk fill
+            # of the scratch dominates at real geometry.)
+            srt = jnp.sort(jnp.where(rd, lpns, cfg.n_logical))
+            dup = jnp.concatenate([jnp.zeros((1,), bool), srt[1:] == srt[:-1]])
+            uniq = jnp.where((srt >= cfg.n_logical) | dup, -1, srt)
+            slot_u, blk_u, mode_u, retr_u, ok_u = lookup(s, uniq, cfg)
+            heat_u = s.heat[jnp.maximum(uniq, 0)]
+            sel = policies.select_migrations(
+                cfg, uniq, mode_u, retr_u, heat_u, ok_u, s.block_pe[blk_u], knobs=knobs
+            )
+            for tgt in (modes.SLC, modes.TLC):
+                s = ftl.maybe_migrate_pages(s, sel[tgt], tgt, cfg, faults=fp)
 
         # ---------------- elastic capacity recovery ----------------
-        if cfg.reclaim_enabled:
-            cls_rd = hotness.classify(s.heat[jnp.maximum(lpns, 0)], cfg.heat)
-            hw = rd & (cls_rd >= modes.WARM)
-            touched_blk = (
-                jax.ops.segment_max(
-                    hw.astype(jnp.int32), blk, num_segments=cfg.n_blocks
+        with jax.named_scope("step_reclaim"):
+            if cfg.reclaim_enabled:
+                cls_rd = hotness.classify(s.heat[jnp.maximum(lpns, 0)], cfg.heat)
+                hw = rd & (cls_rd >= modes.WARM)
+                touched_blk = (
+                    jax.ops.segment_max(
+                        hw.astype(jnp.int32), blk, num_segments=cfg.n_blocks
+                    )
+                    > 0
                 )
-                > 0
-            )
-            s = s._replace(
-                block_cold_age=jnp.where(touched_blk, 0, s.block_cold_age + 1)
-            )
-            free_frac = ftl.free_block_count(s) / cfg.n_blocks
-            rcfg = reclaim.ReclaimConfig(max_per_pass=cfg.max_conversions_per_chunk)
+                s = s._replace(
+                    block_cold_age=jnp.where(touched_blk, 0, s.block_cold_age + 1)
+                )
+                free_frac = ftl.free_block_count(s) / cfg.n_blocks
+                rcfg = reclaim.ReclaimConfig(max_per_pass=cfg.max_conversions_per_chunk)
 
-            def _reclaim_pass(s):
-                # Per-block residual heat = max heat over the block's valid
-                # pages (the demotion tie-breaker: among equally long-cold
-                # blocks, the one with the least residual heat demotes
-                # first). The full-device segment_max is hoisted here so it
-                # runs once per pass and — via the pressure cond below — only
-                # when a demotion can actually fire.
-                slot_blk = (
-                    jnp.arange(cfg.n_slots, dtype=jnp.int32) // cfg.slots_per_block
-                )
-                page_heat = jnp.where(s.p2l >= 0, s.heat[jnp.maximum(s.p2l, 0)], 0.0)
-                block_heat = jnp.maximum(
-                    jax.ops.segment_max(page_heat, slot_blk, num_segments=cfg.n_blocks),
-                    0.0,
-                )
-                victims, v_ok, v_tgt = reclaim.score_victims(
-                    s, cfg, reclaim.DEMOTION, block_heat=block_heat,
-                    free_frac=free_frac, reclaim_cfg=rcfg,
-                )
-                return ftl.reclaim_victims(s, victims, v_ok, v_tgt, cfg,
-                                           faults=fp)
+                def _reclaim_pass(s):
+                    # Per-block residual heat = max heat over the block's valid
+                    # pages (the demotion tie-breaker: among equally long-cold
+                    # blocks, the one with the least residual heat demotes
+                    # first). The full-device segment_max is hoisted here so it
+                    # runs once per pass and — via the pressure cond below — only
+                    # when a demotion can actually fire.
+                    slot_blk = (
+                        jnp.arange(cfg.n_slots, dtype=jnp.int32) // cfg.slots_per_block
+                    )
+                    page_heat = jnp.where(s.p2l >= 0, s.heat[jnp.maximum(s.p2l, 0)], 0.0)
+                    block_heat = jnp.maximum(
+                        jax.ops.segment_max(page_heat, slot_blk, num_segments=cfg.n_blocks),
+                        0.0,
+                    )
+                    victims, v_ok, v_tgt = reclaim.score_victims(
+                        s, cfg, reclaim.DEMOTION, block_heat=block_heat,
+                        free_frac=free_frac, reclaim_cfg=rcfg,
+                    )
+                    return ftl.reclaim_victims(s, victims, v_ok, v_tgt, cfg,
+                                               faults=fp)
 
-            s = lax.cond(
-                free_frac < rcfg.low_watermark, _reclaim_pass, lambda s_: s_, s
-            )
+                s = lax.cond(
+                    free_frac < rcfg.low_watermark, _reclaim_pass, lambda s_: s_, s
+                )
 
     # ---------------- GC (fused multi-victim, deficit-aware) ----------------
-    s = ftl.gc_step(s, cfg, faults=fp, knobs=knobs)
+    with jax.named_scope("step_gc"):
+        s = ftl.gc_step(s, cfg, faults=fp, knobs=knobs)
 
     # clock follows the busiest die (device saturated under FIO load)
-    s = s._replace(clock_ms=jnp.maximum(s.clock_ms, s.die_busy_ms.max()))
+    with jax.named_scope("step_account"):
+        s = s._replace(clock_ms=jnp.maximum(s.clock_ms, s.die_busy_ms.max()))
 
-    if arrival is not None:
-        # block the next chunk's arrivals behind this chunk's background
-        # work, and let wall time follow real arrivals (idle gaps age pages)
-        die_avail = die_avail + (s.die_busy_ms - busy_mark)
-        s = s._replace(
-            die_avail_ms=die_avail,
-            chan_avail_ms=chan_avail,
-            clock_ms=jnp.maximum(
-                s.clock_ms,
-                jnp.maximum(t_arr[-1],
-                            jnp.maximum(die_avail.max(), chan_avail.max())),
-            ),
+        if arrival is not None:
+            # block the next chunk's arrivals behind this chunk's background
+            # work, and let wall time follow real arrivals (idle gaps age pages)
+            die_avail = die_avail + (s.die_busy_ms - busy_mark)
+            s = s._replace(
+                die_avail_ms=die_avail,
+                chan_avail_ms=chan_avail,
+                clock_ms=jnp.maximum(
+                    s.clock_ms,
+                    jnp.maximum(t_arr[-1],
+                                jnp.maximum(die_avail.max(), chan_avail.max())),
+                ),
+            )
+
+        # ---------------- observability: per-window chunk series ----------------
+        if obs.enabled(cfg):
+            s = obs.record_chunk(
+                s, cfg, t_ms=s.clock_ms,
+                writes=s.n_writes - obs0[0],
+                conversions=s.n_conversions.sum() - obs0[1],
+                erases=s.n_erases - obs0[2],
+                migrated=s.n_migrated_pages - obs0[3],
+                reloc=s.n_reloc_pages - obs0[4],
+            )
+
+        nonfree = s.block_state != st.FREE
+        mode_hist = jax.ops.segment_sum(
+            nonfree.astype(jnp.int32), s.block_mode, num_segments=3
         )
-
-    # ---------------- observability: per-window chunk series ----------------
-    if obs.enabled(cfg):
-        s = obs.record_chunk(
-            s, cfg, t_ms=s.clock_ms,
-            writes=s.n_writes - obs0[0],
-            conversions=s.n_conversions.sum() - obs0[1],
-            erases=s.n_erases - obs0[2],
-            migrated=s.n_migrated_pages - obs0[3],
-            reloc=s.n_reloc_pages - obs0[4],
+        y = ChunkMetrics(
+            capacity_pages=st.usable_capacity_pages(s, cfg),
+            free_blocks=ftl.free_block_count(s),
+            mode_hist=mode_hist,
+            reads=chunk_reads,
+            retries=chunk_retries,
+            svc_ms=chunk_svc,
+            migrated=s.n_migrated_pages,
+            lat_hist=chunk_hist,
+            w_lat_hist=chunk_w_hist,
+            q_ms=chunk_q,
+            chanq_ms=chunk_chanw,
+            user_pages=s.n_writes - w_c0,
+            reloc_pages=s.n_reloc_pages - r_c0,
         )
-
-    nonfree = s.block_state != st.FREE
-    mode_hist = jax.ops.segment_sum(
-        nonfree.astype(jnp.int32), s.block_mode, num_segments=3
-    )
-    y = ChunkMetrics(
-        capacity_pages=st.usable_capacity_pages(s, cfg),
-        free_blocks=ftl.free_block_count(s),
-        mode_hist=mode_hist,
-        reads=chunk_reads,
-        retries=chunk_retries,
-        svc_ms=chunk_svc,
-        migrated=s.n_migrated_pages,
-        lat_hist=chunk_hist,
-        w_lat_hist=chunk_w_hist,
-        q_ms=chunk_q,
-        chanq_ms=chunk_chanw,
-        user_pages=s.n_writes - w_c0,
-        reloc_pages=s.n_reloc_pages - r_c0,
-    )
     return s, y
 
 
@@ -795,16 +803,20 @@ def run(cfg: geometry.SimConfig, trace, has_writes: bool | None = None):
     shape switches the engine to the open-loop arrival model. Returns
     (final_state, ChunkMetrics stacked).
     """
-    if has_writes is None:
-        has_writes = bool((trace["op"] == OP_WRITE).any())
-    lpns = jnp.asarray(trace["lpn"], jnp.int32)
-    ops = jnp.asarray(trace["op"], jnp.int32)
-    if "arrival_ms" in trace:
-        arr = jnp.asarray(trace["arrival_ms"], jnp.float32)
-        return _run_open_jit(cfg, lpns, ops, arr, has_writes)
-    return _run_jit(cfg, lpns, ops, has_writes)
+    with jax.profiler.TraceAnnotation("repro.run.put"):
+        if has_writes is None:
+            has_writes = bool((trace["op"] == OP_WRITE).any())
+        lpns = jnp.asarray(trace["lpn"], jnp.int32)
+        ops = jnp.asarray(trace["op"], jnp.int32)
+        arr = (jnp.asarray(trace["arrival_ms"], jnp.float32)
+               if "arrival_ms" in trace else None)
+    with jax.profiler.TraceAnnotation("repro.run.dispatch"):
+        if arr is not None:
+            return _run_open_jit(cfg, lpns, ops, arr, has_writes)
+        return _run_jit(cfg, lpns, ops, has_writes)
 
 
+@partial(jax.profiler.annotate_function, name="repro.summarize")
 def summarize(s: st.SSDState, cfg: geometry.SimConfig, threads: int = 4):
     """Headline numbers for the paper's figures.
 
