@@ -193,7 +193,9 @@ def _run_batch(cfg: geometry.SimConfig, has_writes: bool, lpns, ops,
 
     ``lpns``/``ops``: (R, n_chunks, chunk); ``knobs``: (R,) fields;
     ``arrival_ms``: (R, n_chunks, chunk) f32 or None (closed loop). Returns
-    the stacked final state pytree (leading run axis on every leaf).
+    the stacked final state pytree (leading run axis on every leaf). The
+    vmap axis is named ``_LANE_AXIS`` so the reclaim and GC passes run in a
+    chunk only when some run of the batch needs them (``ftl.gated``).
     """
 
     def one(lpns_i, ops_i, knobs_i, arr_i=None):
@@ -201,15 +203,17 @@ def _run_batch(cfg: geometry.SimConfig, has_writes: bool, lpns, ops,
                            spare_blocks=knobs_i.spare_blocks)
 
         def body(s, x):
-            return engine.step_chunk(s, x, cfg, has_writes, knobs_i)
+            return engine.step_chunk(s, x, cfg, has_writes, knobs_i,
+                                     lane_axis=_LANE_AXIS)
 
         xs = (lpns_i, ops_i) if arr_i is None else (lpns_i, ops_i, arr_i)
         s, _ = lax.scan(body, s0, xs)
         return s
 
+    batched = jax.vmap(one, axis_name=_LANE_AXIS)
     if arrival_ms is None:
-        return jax.vmap(one)(lpns, ops, knobs)
-    return jax.vmap(one)(lpns, ops, knobs, arrival_ms)
+        return batched(lpns, ops, knobs)
+    return batched(lpns, ops, knobs, arrival_ms)
 
 
 @partial(jax.jit, static_argnums=(0, 3))
@@ -225,8 +229,9 @@ def _sweep_sharded_jit(cfg: geometry.SimConfig, lpns, ops, has_writes: bool,
     """Sharded executor: the run axis (a multiple of the mesh size — the
     caller pads) is split across ``mesh``'s devices via ``shard_map``; each
     device runs the identical vmapped program on its local runs, so results
-    are bitwise identical to the single-device path. No collectives — runs
-    are independent, making the shard axis embarrassingly parallel."""
+    are bitwise identical to the single-device path. No collectives across
+    devices — runs are independent, making the shard axis embarrassingly
+    parallel; the gated passes reduce over each device's own lanes only."""
     spec = P(_MESH_AXIS)
     # check_vma=False: every value is per-device (no collectives, nothing
     # replicated), so there is nothing for the varying-axes check to prove
@@ -246,6 +251,9 @@ def _sweep_sharded_jit(cfg: geometry.SimConfig, lpns, ops, has_writes: bool,
 
 
 _MESH_AXIS = "runs"
+# the vmap axis over one device's runs: the watermark-gated passes reduce
+# their predicates over it (``ftl.gated``), never over the mesh
+_LANE_AXIS = "lanes"
 
 
 def resolve_devices(devices):

@@ -421,11 +421,15 @@ def write_path_batched(s: st.SSDState, lpns, is_write, cfg: geometry.SimConfig,
 
 
 def step_chunk(s: st.SSDState, req, cfg: geometry.SimConfig, has_writes: bool,
-               knobs: policies.RunKnobs | None = None):
+               knobs: policies.RunKnobs | None = None,
+               lane_axis: str | None = None):
     """One engine step. ``req`` is ``(lpns, ops)`` for the closed-loop model
     or ``(lpns, ops, arrival_ms)`` for the open-loop arrival model. ``knobs``
     optionally supplies traced overrides for the batchable policy/wear knobs
-    (sweep runner); ``None`` reads them from ``cfg`` as before."""
+    (sweep runner); ``None`` reads them from ``cfg`` as before.
+    ``lane_axis`` is the name of the vmap axis when the step runs batched
+    (sweep runner): the watermark-gated reclaim and GC passes then run in a
+    chunk only when some lane of the batch needs them (``ftl.gated``)."""
     lpns, ops = req[0], req[1]
     arrival = req[2] if len(req) == 3 else None
     with jax.named_scope("step_read"):
@@ -717,13 +721,14 @@ def step_chunk(s: st.SSDState, req, cfg: geometry.SimConfig, has_writes: bool,
                     return ftl.reclaim_victims(s, victims, v_ok, v_tgt, cfg,
                                                faults=fp)
 
-                s = lax.cond(
-                    free_frac < rcfg.low_watermark, _reclaim_pass, lambda s_: s_, s
+                s, opened = ftl.gated(
+                    free_frac < rcfg.low_watermark, _reclaim_pass, s, lane_axis
                 )
+                s = obs.record_gate(s, cfg, obs.GATE_RECLAIM, opened)
 
     # ---------------- GC (fused multi-victim, deficit-aware) ----------------
     with jax.named_scope("step_gc"):
-        s = ftl.gc_step(s, cfg, faults=fp, knobs=knobs)
+        s = ftl.gc_step(s, cfg, faults=fp, knobs=knobs, lane_axis=lane_axis)
 
     # clock follows the busiest die (device saturated under FIO load)
     with jax.named_scope("step_account"):

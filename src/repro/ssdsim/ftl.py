@@ -660,17 +660,39 @@ def select_gc_victims(s: st.SSDState, cfg: geometry.SimConfig, k: int,
     return victims, ok
 
 
+def gated(pred, fn, s, lane_axis: str | None = None):
+    """``lax.cond(pred, fn, identity, s)`` that stays a branch under vmap.
+
+    Under ``jax.vmap`` a cond on a batched predicate lowers to a select, so
+    ``fn`` would run for every lane on every call. Given the vmap's
+    ``lane_axis`` name, the pass sits behind one scalar cond on whether any
+    lane of the batch needs it; inside, the per-lane cond (that same
+    select) keeps the state of lanes that do not, so each lane's result is
+    bit-identical to its own unbatched run. ``lane_axis=None`` is the plain
+    cond. Returns ``(state, opened)``: ``opened`` is the scalar predicate of
+    the branch taken, for :func:`obs.record_gate`."""
+    keep = lambda s_: s_  # noqa: E731
+    if lane_axis is None:
+        return lax.cond(pred, fn, keep, s), pred
+    opened = lax.psum(pred.astype(jnp.int32), lane_axis) > 0
+    return lax.cond(opened, lambda s_: lax.cond(pred, fn, keep, s_), keep,
+                    s), opened
+
+
 def gc_step(s: st.SSDState, cfg: geometry.SimConfig,
-            faults: flt.FaultParams | None = None, knobs=None):
+            faults: flt.FaultParams | None = None, knobs=None,
+            lane_axis: str | None = None):
     """Fused greedy GC, cond-gated on the free-pool watermark: with a
     healthy pool the victim scan is skipped entirely, so GC can never fire
     above ``cfg.gc_free_threshold``. Under pressure one firing relocates up
     to ``cfg.gc_victims_per_pass`` victims through :func:`relocate_group`,
     amortizing the full-device top-k, the placement unroll and the per-chunk
-    dispatch over k blocks."""
+    dispatch over k blocks. ``lane_axis`` names the vmap axis of a batched
+    caller (see :func:`gated`)."""
     need = free_block_count(s) < cfg.gc_free_threshold
-    return lax.cond(need, lambda s_: _gc_pass(s_, cfg, faults, knobs),
-                    lambda s_: s_, s)
+    s, opened = gated(need, lambda s_: _gc_pass(s_, cfg, faults, knobs), s,
+                      lane_axis)
+    return obs.record_gate(s, cfg, obs.GATE_GC, opened)
 
 
 def _gc_pass(s: st.SSDState, cfg: geometry.SimConfig,

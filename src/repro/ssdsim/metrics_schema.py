@@ -72,6 +72,8 @@ SCHEMA: dict[str, Metric] = {
     # ---- observability (DESIGN.md §7.4) ----
     "lat_mode_counts": Metric("reads", "(3, N_LAT_BINS) per-mode read histogram",
                               scalar=False),
+    "gated_pass_chunks": Metric("chunks", "(2,) chunks in which the reclaim and "
+                                          "GC passes ran", scalar=False),
     "lat_attrib_us": Metric("us", "(3, N_COMPONENTS) latency attribution sums",
                             scalar=False),
     "tail_retry_share": Metric("share", "(3,) retry share of each mode's p99 tail",

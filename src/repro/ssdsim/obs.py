@@ -39,9 +39,10 @@ leaves on :class:`repro.ssdsim.state.SSDState`:
 Cost model (``cfg.obs_level``): ``"off"`` traces **no** observability ops at
 all — every obs leaf is zero-length, so the scan carry and compiled program
 are unchanged up to empty arrays (the PR 4/5 regression gate guards the
-claim). ``"counters"`` adds the per-mode count histograms and the time
-series (a handful of scatter-adds per chunk). ``"full"`` adds the component
-decomposition and the event ring buffer.
+claim). ``"counters"`` adds the per-mode count histograms, the time
+series (a handful of scatter-adds per chunk) and the count of chunks in
+which each watermark-gated pass ran (``obs_gated_chunks``). ``"full"`` adds
+the component decomposition and the event ring buffer.
 
 Host-side decoders (numpy, usable on device_get'ed sweep leaves) live at the
 bottom: :func:`decode_events`, :func:`event_conversion_matrix`,
@@ -114,6 +115,13 @@ SERIES_NAMES = (
     "migrated_pages", "uncorrectable", "reloc_pages",
 )
 
+# watermark-gated background passes (``ftl.gated``): the chunks in which each
+# ran. In a vmapped sweep a pass runs for the whole batch when any lane of it
+# needs the pass, so every lane of a batch counts the same chunks.
+GATE_RECLAIM = 0
+GATE_GC = 1
+N_GATES = 2
+
 
 def enabled(cfg: geometry.SimConfig) -> bool:
     """Counters or better are being collected (trace-time gate)."""
@@ -149,6 +157,8 @@ def init_leaves(cfg: geometry.SimConfig) -> dict:
         obs_events=jnp.zeros((cap, N_EV_FIELDS), jnp.float32),
         obs_ev_count=jnp.int32(0),
         obs_ts=jnp.zeros((win, N_SERIES), jnp.float32),
+        obs_gated_chunks=jnp.zeros((N_GATES if enabled(cfg) else 0,),
+                                   jnp.int32),
     )
 
 
@@ -242,6 +252,18 @@ def record_chunk(s, cfg: geometry.SimConfig, *, t_ms, writes, conversions,
     for row, v in rows:
         ts = ts.at[w, row].add(jnp.asarray(v, jnp.float32))
     return s._replace(obs_ts=ts)
+
+
+def record_gate(s, cfg: geometry.SimConfig, gate: int, opened):
+    """Count the chunk in ``gate``'s slot when its pass ran (``opened``, the
+    scalar predicate of the branch ``ftl.gated`` took)."""
+    if not enabled(cfg):
+        return s
+    return s._replace(
+        obs_gated_chunks=s.obs_gated_chunks.at[gate].add(
+            jnp.asarray(opened, jnp.int32)
+        )
+    )
 
 
 def record_events(s, cfg: geometry.SimConfig, *, mask, block, from_mode,
@@ -408,6 +430,8 @@ def summary(s, cfg: geometry.SimConfig) -> dict:
     ``full``):
 
     - ``lat_mode_counts`` — (3, N_LAT_BINS) per-mode read-count histogram
+    - ``gated_pass_chunks`` — (N_GATES,) chunks in which the reclaim and GC
+      passes ran (for a sweep lane: chunks its batch ran them)
     - ``lat_attrib_us`` — (3, N_COMPONENTS) total µs per mode x component
     - ``tail_retry_share`` — (3,) retry share of each mode's p99 tail mass
     - ``conversion_events`` — (3, 3) decoded from-x-to event counts (equals
@@ -416,7 +440,10 @@ def summary(s, cfg: geometry.SimConfig) -> dict:
     """
     if not enabled(cfg):
         return {}
-    out = {"lat_mode_counts": np.asarray(s.obs_lat_mode, np.float64).tolist()}
+    out = {
+        "lat_mode_counts": np.asarray(s.obs_lat_mode, np.float64).tolist(),
+        "gated_pass_chunks": np.asarray(s.obs_gated_chunks, np.float64).tolist(),
+    }
     if not full(cfg):
         return out
     comp = np.asarray(s.obs_lat_comp, np.float64)

@@ -102,6 +102,7 @@ class SSDState(NamedTuple):
     obs_events: jnp.ndarray  # (capacity|0, N_EV_FIELDS) f32 event ring
     obs_ev_count: jnp.ndarray  # i32 scalar — true total events emitted
     obs_ts: jnp.ndarray  # (windows|0, N_SERIES) windowed time series
+    obs_gated_chunks: jnp.ndarray  # (N_GATES|0,) i32 chunks each gated pass ran
 
     # counters (f32 scalars; summed per-chunk so precision is fine)
     svc_sum_ms: jnp.ndarray  # total recorded user-read latency (queueing

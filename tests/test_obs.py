@@ -4,13 +4,15 @@ conversion event ring, windowed time series, exporters."""
 import json
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from hyp_fallback import given, settings
 from hyp_fallback import st as st_h
 
 from repro.core import modes
-from repro.ssdsim import engine, geometry, obs, state as st, trace_export, workload
+from repro.experiments import registry
+from repro.ssdsim import engine, ftl, geometry, obs, state as st, trace_export, workload
 
 
 def _full_cfg(**kw):
@@ -286,6 +288,7 @@ class TestLevelsAndSummarize:
         assert s.obs_lat_comp.shape[0] == 0
         assert s.obs_events.shape[0] == 0
         assert s.obs_ts.shape[0] == 0
+        assert s.obs_gated_chunks.shape[0] == 0
 
     def test_off_summarize_has_no_obs_keys(self):
         cfg = geometry.tiny_config(policy=geometry.RARO, initial_pe=500)
@@ -294,7 +297,7 @@ class TestLevelsAndSummarize:
         m = engine.summarize(s, cfg)
         assert not any(
             k.startswith(("lat_mode", "lat_attrib", "obs_", "tail_",
-                          "conversion_events"))
+                          "conversion_events", "gated_pass"))
             for k in m
         )
 
@@ -308,6 +311,43 @@ class TestLevelsAndSummarize:
         assert s.obs_lat_comp.shape[0] == 0 and s.obs_events.shape[0] == 0
         m = engine.summarize(s, cfg)
         assert "lat_mode_counts" in m and "lat_attrib_us" not in m
+        assert len(m["gated_pass_chunks"]) == obs.N_GATES
+
+    def test_gate_counter_counts_chunks_its_gates_opened(self, monkeypatch):
+        """A single drive under pressure: ``obs_gated_chunks`` counts exactly
+        the chunks in which its reclaim and GC gates were open, read off
+        the predicates ``ftl.gated`` receives, one jitted chunk at a time."""
+        cfg = geometry.tiny_config(
+            policy=geometry.RARO, initial_pe=2500, n_logical=2560,
+            gc_free_threshold=10, obs_level="counters")
+        n_chunks = 16
+        tr = registry.build("hotspot_shift", cfg, n_chunks * cfg.chunk,
+                            seed=0)
+        s_run, _ = engine.run(cfg, tr)
+
+        seen, gated = [], ftl.gated
+
+        def spy(pred, fn, s, lane_axis=None):
+            seen.append(pred)
+            return gated(pred, fn, s, lane_axis)
+
+        monkeypatch.setattr(ftl, "gated", spy)
+
+        @jax.jit
+        def step(s, lpns, ops):
+            seen.clear()
+            s, _ = engine.step_chunk(s, (lpns, ops), cfg, True)
+            return s, jnp.stack(seen).astype(jnp.int32)  # [reclaim, gc]
+
+        s, opened = st.init_state(cfg), np.zeros(obs.N_GATES, np.int64)
+        for c in range(n_chunks):
+            s, p = step(s, jnp.asarray(tr["lpn"][c]), jnp.asarray(tr["op"][c]))
+            opened += np.asarray(p)
+        counted = np.asarray(s_run.obs_gated_chunks)
+        np.testing.assert_array_equal(counted, opened)
+        np.testing.assert_array_equal(np.asarray(s.obs_gated_chunks), opened)
+        # the gates opened in some chunks and stayed shut in others
+        assert (0 < counted).all() and (counted < n_chunks).all(), counted
 
     def test_invalid_level_rejected(self):
         with pytest.raises(ValueError, match="obs_level"):

@@ -9,6 +9,7 @@ this file.
 """
 
 import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +18,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
 import chip_smoke
+from bench import harness
 from repro.core import modes
 from repro.experiments import sweep
 from repro.kernels.flash_attention.ops import flash_attention
@@ -27,6 +29,7 @@ from repro.launch import serve
 from repro.ssdsim import engine, geometry, policies
 
 V5E_HBM_BYTES = 16 * 2**30
+REPO = Path(__file__).resolve().parent.parent
 
 
 @pytest.fixture(scope="module")
@@ -92,6 +95,23 @@ def test_sweep_sharded_compiles_on_four_chips(topo):
     compiled = sweep._sweep_sharded_jit.lower(
         cfg, lpns, lpns, False, knobs, None, mesh).compile()
     _fits(compiled)
+
+
+def test_table3_age_grid_sweep_fits_one_chip(one_chip):
+    """The benchmark's twelve-lane Table III grid as ``run_sweep`` compiles
+    it on one chip, with its reclaim and GC passes behind the batch gate."""
+    cell = harness.find_cell("table3_age_grid.fio_zipf_read", REPO)
+    cfg = harness.sim_config(cell.config)
+    assert (cfg.n_blocks, cfg.slots_per_block) == (1024, 1024)
+    r = len(cell.config["initial_pe"]) * cell.config["seeds_per_stage"]
+    n_chunks = -(-cell.config["requests_per_run"] // cfg.chunk)
+    x = jax.ShapeDtypeStruct((r, n_chunks, cfg.chunk), jnp.int32,
+                             sharding=one_chip)
+    k = jax.ShapeDtypeStruct((r,), jnp.int32, sharding=one_chip)
+    knobs = policies.RunKnobs(r1=k, r2_override=k, initial_pe=k)
+    compiled = sweep._sweep_jit.lower(cfg, x, x, False, knobs).compile()
+    _fits(compiled)
+    assert " conditional(" in compiled.as_text()
 
 
 def _tiered_args(one_chip):
