@@ -506,10 +506,11 @@ def run_sweep(spec: SweepSpec, threads: int = 4, verbose: bool = False,
             with jax.profiler.TraceAnnotation("repro.sweep.dispatch"):
                 if mesh is None:
                     return _sweep_jit(cfg, lpns, ops, has_writes, knobs, arr)
-                place = lambda x: jax.device_put(x, run_sharding)  # noqa: E731
-                lpns, ops = place(lpns), place(ops)
-                arr = place(arr) if arr is not None else None
-                knobs = jax.tree_util.tree_map(place, knobs)
+                with jax.profiler.TraceAnnotation("repro.sweep.place"):
+                    place = lambda x: jax.device_put(x, run_sharding)  # noqa: E731
+                    lpns, ops = place(lpns), place(ops)
+                    arr = place(arr) if arr is not None else None
+                    knobs = jax.tree_util.tree_map(place, knobs)
                 return _sweep_sharded_jit(cfg, lpns, ops, has_writes, knobs,
                                           arr, mesh)
 

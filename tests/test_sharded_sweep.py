@@ -107,6 +107,34 @@ class TestShardedEquivalence:
         with pytest.raises(ValueError, match="devices"):
             sweep.run_sweep(_spec(), devices=0)
 
+    @pytest.mark.parametrize("devices", [None, 1], ids=["vmap", "shard_map"])
+    def test_place_span_only_on_mesh_path(self, devices, monkeypatch):
+        """The sharded placement of the stacked inputs runs in its own
+        ``repro.sweep.place`` span inside ``repro.sweep.dispatch``; the vmap
+        path, which lets jit move its inputs, opens none."""
+        opened, stack = [], []
+
+        class Span:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                opened.append((self.name, tuple(stack)))
+                stack.append(self.name)
+
+            def __exit__(self, *exc):
+                stack.pop()
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Span)
+        sweep.run_sweep(_spec(), devices=devices)
+        place = [outer for name, outer in opened if name == "repro.sweep.place"]
+        dispatches = sum(name == "repro.sweep.dispatch" for name, _ in opened)
+        assert dispatches == len(_spec().policies)
+        if devices is None:
+            assert place == []
+        else:
+            assert place == [("repro.sweep.dispatch",)] * dispatches
+
 
 # --------------- watermark-gated passes under vmap (ftl.gated) ---------------
 

@@ -114,6 +114,29 @@ def test_table3_age_grid_sweep_fits_one_chip(one_chip):
     assert " conditional(" in compiled.as_text()
 
 
+def test_table3_age_grid_4chip_sweep_fits_each_chip(topo):
+    """The four-chip benchmark grid as ``run_sweep(devices=4)`` compiles it:
+    48 Table III lanes of 64 chunks split 12 a chip over a 2x2 mesh, each
+    chip's share within its memory, and no collective across chips."""
+    cell = harness.find_cell("table3_age_grid_4chip.fio_zipf_read", REPO)
+    cfg = harness.sim_config(cell.config)
+    r = len(cell.config["initial_pe"]) * cell.config["seeds_per_stage"]
+    n_chunks = -(-cell.config["requests_per_run"] // cfg.chunk)
+    assert (cell.chips, r, n_chunks, cfg.chunk) == (4, 48, 64, 1024)
+    mesh = Mesh(np.asarray(topo.devices[:cell.chips]), ("runs",))
+    runs = NamedSharding(mesh, PartitionSpec("runs"))
+    x = jax.ShapeDtypeStruct((r, n_chunks, cfg.chunk), jnp.int32, sharding=runs)
+    k = jax.ShapeDtypeStruct((r,), jnp.int32, sharding=runs)
+    knobs = policies.RunKnobs(r1=k, r2_override=k, initial_pe=k)
+    compiled = sweep._sweep_sharded_jit.lower(
+        cfg, x, x, False, knobs, None, mesh).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    assert " conditional(" in text
+    for collective in ("all-reduce(", "all-gather(", "collective-permute(", "all-to-all("):
+        assert collective not in text
+
+
 def _tiered_args(one_chip):
     mcfg = serve.serve_cfg()
     ccfg = serve.serve_cache_cfg(mcfg, steps=64, batch=4)
