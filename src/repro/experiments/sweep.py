@@ -195,20 +195,24 @@ def _run_batch(cfg: geometry.SimConfig, has_writes: bool, lpns, ops,
     ``arrival_ms``: (R, n_chunks, chunk) f32 or None (closed loop). Returns
     the stacked final state pytree (leading run axis on every leaf). The
     vmap axis is named ``_LANE_AXIS`` so the reclaim and GC passes run in a
-    chunk only when some run of the batch needs them (``ftl.gated``).
+    chunk only when some run of the batch needs them (``ftl.gated``). The
+    scan carries the large per-slot and per-logical leaves 128 wide
+    (``state.fold_lanes``), so the batched scatters into them need no
+    relayout copy; ``step_chunk`` sees them 1-D.
     """
 
     def one(lpns_i, ops_i, knobs_i, arr_i=None):
         s0 = st.init_state(cfg, initial_pe=knobs_i.initial_pe,
                            spare_blocks=knobs_i.spare_blocks)
 
-        def body(s, x):
-            return engine.step_chunk(s, x, cfg, has_writes, knobs_i,
-                                     lane_axis=_LANE_AXIS)
+        def body(f, x):
+            s, y = engine.step_chunk(st.unfold_lanes(f, s0), x, cfg,
+                                     has_writes, knobs_i, lane_axis=_LANE_AXIS)
+            return st.fold_lanes(s), y
 
         xs = (lpns_i, ops_i) if arr_i is None else (lpns_i, ops_i, arr_i)
-        s, _ = lax.scan(body, s0, xs)
-        return s
+        f, _ = lax.scan(body, st.fold_lanes(s0), xs)
+        return st.unfold_lanes(f, s0)
 
     batched = jax.vmap(one, axis_name=_LANE_AXIS)
     if arrival_ms is None:

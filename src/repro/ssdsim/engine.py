@@ -134,15 +134,18 @@ def _tandem_departures(die_avail0, chan_avail0, arrival_ms, die_occ_ms,
     return die_dep, chan_dep, die_avail, chan_avail
 
 
-def lookup(s: st.SSDState, lpns, cfg: geometry.SimConfig):
-    """Gather physical metadata + Eq.-3 retry estimate for logical pages."""
+def lookup(s: st.SSDState, lpns, cfg: geometry.SimConfig, wide: bool = False):
+    """Gather physical metadata + Eq.-3 retry estimate for logical pages.
+    ``wide`` reads the mapping and timestamps through their 128-wide views
+    (``state.take``), as a vmapped step does."""
     lp = jnp.maximum(lpns, 0)
-    slot = s.l2p[lp]
+    slot = st.take(s.l2p, lp, wide)
     ok = (lpns >= 0) & (slot >= 0)
     slot = jnp.where(ok, slot, 0)
     blk = slot // cfg.slots_per_block
     mode = s.block_mode[blk]
-    age_h = cfg.device_age_h + (s.clock_ms - s.page_write_ms[slot]) / 3.6e6
+    age_h = cfg.device_age_h + (
+        s.clock_ms - st.take(s.page_write_ms, slot, wide)) / 3.6e6
     retries = retry.page_retries(mode, s.block_pe[blk], age_h, s.block_reads[blk], slot)
     return slot, blk, mode, retries, ok
 
@@ -429,9 +432,13 @@ def step_chunk(s: st.SSDState, req, cfg: geometry.SimConfig, has_writes: bool,
     (sweep runner); ``None`` reads them from ``cfg`` as before.
     ``lane_axis`` is the name of the vmap axis when the step runs batched
     (sweep runner): the watermark-gated reclaim and GC passes then run in a
-    chunk only when some lane of the batch needs them (``ftl.gated``)."""
+    chunk only when some lane of the batch needs them (``ftl.gated``), a
+    lane that may not convert passes its conversions no page
+    (``ftl.maybe_migrate_pages``), and the lookups index the mapping and
+    timestamps 128 wide (``state.take``)."""
     lpns, ops = req[0], req[1]
     arrival = req[2] if len(req) == 3 else None
+    wide = lane_axis is not None
     with jax.named_scope("step_read"):
         is_read = ops == OP_READ
         fp = flt.params_for(cfg, knobs)  # None = no fault ops traced at all
@@ -440,7 +447,7 @@ def step_chunk(s: st.SSDState, req, cfg: geometry.SimConfig, has_writes: bool,
         w_c0, r_c0 = s.n_writes, s.n_reloc_pages
 
         # ---------------- reads (vectorized) ----------------
-        slot, blk, mode, retries, ok = lookup(s, lpns, cfg)
+        slot, blk, mode, retries, ok = lookup(s, lpns, cfg, wide)
         rd = is_read & ok
         svc_us = jnp.where(rd, retry.read_latency_us(mode, retries), 0.0)
         if fp is not None:
@@ -674,13 +681,14 @@ def step_chunk(s: st.SSDState, req, cfg: geometry.SimConfig, has_writes: bool,
             srt = jnp.sort(jnp.where(rd, lpns, cfg.n_logical))
             dup = jnp.concatenate([jnp.zeros((1,), bool), srt[1:] == srt[:-1]])
             uniq = jnp.where((srt >= cfg.n_logical) | dup, -1, srt)
-            slot_u, blk_u, mode_u, retr_u, ok_u = lookup(s, uniq, cfg)
+            slot_u, blk_u, mode_u, retr_u, ok_u = lookup(s, uniq, cfg, wide)
             heat_u = s.heat[jnp.maximum(uniq, 0)]
             sel = policies.select_migrations(
                 cfg, uniq, mode_u, retr_u, heat_u, ok_u, s.block_pe[blk_u], knobs=knobs
             )
             for tgt in (modes.SLC, modes.TLC):
-                s = ftl.maybe_migrate_pages(s, sel[tgt], tgt, cfg, faults=fp)
+                s = ftl.maybe_migrate_pages(s, sel[tgt], tgt, cfg, faults=fp,
+                                            lane_axis=lane_axis)
 
         # ---------------- elastic capacity recovery ----------------
         with jax.named_scope("step_reclaim"):

@@ -270,7 +270,7 @@ def _erase(s: st.SSDState, blk, cfg: geometry.SimConfig):
 
 
 def _place_pages(s: st.SSDState, lpns, valid, tgt_mode, cfg: geometry.SimConfig,
-                 n_dest: int):
+                 n_dest: int, wide: bool = False):
     """Append the ``valid``-masked ``lpns`` into open migration block(s) of
     ``tgt_mode``, opening up to ``n_dest`` fresh blocks from the free pool.
 
@@ -284,7 +284,8 @@ def _place_pages(s: st.SSDState, lpns, valid, tgt_mode, cfg: geometry.SimConfig,
     lane's destination slot; every lane is placed in exactly one iteration,
     so the expensive full-array scatters (l2p/p2l/page timestamps) happen
     once after the loop instead of once per destination — the unroll cost
-    no longer scales with the lane count.
+    no longer scales with the lane count. ``wide`` does those scatters
+    through the 128-wide views (:func:`state.put`).
     """
     spb = cfg.slots_per_block
     ppb = geometry.pages_per_block(cfg)
@@ -350,9 +351,9 @@ def _place_pages(s: st.SSDState, lpns, valid, tgt_mode, cfg: geometry.SimConfig,
     placed = dest_slot < S
     lp_idx = jnp.where(placed, lpns, L)  # L = dropped
     return s._replace(
-        l2p=s.l2p.at[lp_idx].set(dest_slot, mode="drop"),
-        p2l=s.p2l.at[dest_slot].set(lp_safe, mode="drop"),
-        page_write_ms=s.page_write_ms.at[dest_slot].set(s.clock_ms, mode="drop"),
+        l2p=st.put(s.l2p, lp_idx, dest_slot, wide),
+        p2l=st.put(s.p2l, dest_slot, lp_safe, wide),
+        page_write_ms=st.put(s.page_write_ms, dest_slot, s.clock_ms, wide),
         # every physical relocation program is a page of write amplification;
         # counting here (the single placement core) covers GC, reclaim,
         # conversion AND prog-fail re-placement with one counter
@@ -420,7 +421,7 @@ def _dest_unroll(cfg: geometry.SimConfig, n_pages: int) -> int:
 
 
 def migrate_pages(s: st.SSDState, lpns, tgt_mode, cfg: geometry.SimConfig,
-                  faults: flt.FaultParams | None = None):
+                  faults: flt.FaultParams | None = None, wide: bool = False):
     """Page-granular conversion migration (paper Fig. 9/10): move the given
     logical pages into open block(s) programmed in ``tgt_mode``, invalidating
     their old slots. The destination block is the unit of mode uniformity
@@ -429,13 +430,16 @@ def migrate_pages(s: st.SSDState, lpns, tgt_mode, cfg: geometry.SimConfig,
     ``lpns``: (M,) int32, -1-padded. M is static (cfg.migrate_pages_per_chunk).
     With ``faults`` active, over-budget migration reads pay the ECC recovery
     penalty and count as uncorrectable (same model as :func:`relocate_group`).
+    ``wide`` indexes the mapping and timestamps through their 128-wide views
+    (:func:`state.put`). With no valid page the call leaves ``s`` exactly as it
+    was: every scatter drops and every add is of zero to a non-negative sum.
     """
     spb = cfg.slots_per_block
     S = cfg.n_slots
     M = lpns.shape[0]
 
     lp_safe = jnp.maximum(lpns, 0)
-    old_slot = s.l2p[lp_safe]
+    old_slot = st.take(s.l2p, lp_safe, wide)
     valid = (lpns >= 0) & (old_slot >= 0)
     old_slot = jnp.where(valid, old_slot, 0)
     src_blk = old_slot // spb
@@ -445,7 +449,8 @@ def migrate_pages(s: st.SSDState, lpns, tgt_mode, cfg: geometry.SimConfig,
     n_valid = valid.sum()
 
     # -- read cost of sources (each page is re-read to migrate) --
-    age_h = cfg.device_age_h + (s.clock_ms - s.page_write_ms[old_slot]) / 3.6e6
+    age_h = cfg.device_age_h + (
+        s.clock_ms - st.take(s.page_write_ms, old_slot, wide)) / 3.6e6
     retries = retry.page_retries(src_mode, s.block_pe[src_blk], age_h, s.block_reads[src_blk], old_slot)
     lat_us = retry.read_latency_us(src_mode, retries)
     if faults is not None:
@@ -466,11 +471,11 @@ def migrate_pages(s: st.SSDState, lpns, tgt_mode, cfg: geometry.SimConfig,
 
     # -- invalidate old slots --
     drop_slot = jnp.where(valid, old_slot, S)
-    p2l = s.p2l.at[drop_slot].set(-1, mode="drop")
+    p2l = st.put(s.p2l, drop_slot, -1, wide)
     bv = s.block_valid - jax.ops.segment_sum(valid.astype(jnp.int32), src_blk, num_segments=s.block_valid.shape[0])
     s = s._replace(p2l=p2l, block_valid=bv)
 
-    s = _place_pages(s, lpns, valid, tgt_mode, cfg, _dest_unroll(cfg, M))
+    s = _place_pages(s, lpns, valid, tgt_mode, cfg, _dest_unroll(cfg, M), wide)
 
     conv = jax.ops.segment_sum(valid.astype(jnp.float32), src_mode, num_segments=3)
     s = s._replace(
@@ -499,9 +504,28 @@ def migrate_pages(s: st.SSDState, lpns, tgt_mode, cfg: geometry.SimConfig,
 
 
 def maybe_migrate_pages(s: st.SSDState, lpns, tgt_mode, cfg: geometry.SimConfig,
-                        faults: flt.FaultParams | None = None):
+                        faults: flt.FaultParams | None = None,
+                        lane_axis: str | None = None):
+    """:func:`migrate_pages` when some page is given and the free pool has
+    room.
+
+    Under a vmap (``lane_axis`` named) a cond on a batched predicate is a
+    select of the whole state, and the select pins the large leaves to a
+    layout the migration's scatters must copy them out of. So the lanes that
+    may not migrate pass no page instead, which leaves their large leaves
+    exactly as they were (every scatter drops), and the migration indexes
+    them 128 wide. The small leaves keep the select: it costs nothing and
+    keeps their float sums in the order the unbatched cond gives them
+    (without it XLA adds the two migrations' read costs into the die clocks
+    in different orders, an ulp off the lane's own run)."""
     any_valid = (lpns >= 0).any()
     ok = any_valid & (free_block_count(s) >= _dest_unroll(cfg, lpns.shape[0]) + 2)
+    if lane_axis is not None:
+        m = migrate_pages(s, jnp.where(ok, lpns, -1), tgt_mode, cfg, faults,
+                          wide=True)
+        return jax.tree.map(
+            lambda new, old: new if st.foldable(new) else jnp.where(ok, new, old),
+            m, s)
     return lax.cond(
         ok,
         lambda s_: migrate_pages(s_, lpns, tgt_mode, cfg, faults),
@@ -669,14 +693,20 @@ def gated(pred, fn, s, lane_axis: str | None = None):
     lane of the batch needs it; inside, the per-lane cond (that same
     select) keeps the state of lanes that do not, so each lane's result is
     bit-identical to its own unbatched run. ``lane_axis=None`` is the plain
-    cond. Returns ``(state, opened)``: ``opened`` is the scalar predicate of
-    the branch taken, for :func:`obs.record_gate`."""
+    cond. The batch cond's operands and results carry the state 128 wide
+    (``state.fold_lanes``), as the sweep's scan carry does, so no relayout
+    copy sits around it. Returns ``(state, opened)``: ``opened`` is the
+    scalar predicate of the branch taken, for :func:`obs.record_gate`."""
     keep = lambda s_: s_  # noqa: E731
     if lane_axis is None:
         return lax.cond(pred, fn, keep, s), pred
     opened = lax.psum(pred.astype(jnp.int32), lane_axis) > 0
-    return lax.cond(opened, lambda s_: lax.cond(pred, fn, keep, s_), keep,
-                    s), opened
+
+    def run(f):
+        return st.fold_lanes(lax.cond(pred, fn, keep, st.unfold_lanes(f, s)))
+
+    f = lax.cond(opened, run, keep, st.fold_lanes(s))
+    return st.unfold_lanes(f, s), opened
 
 
 def gc_step(s: st.SSDState, cfg: geometry.SimConfig,

@@ -228,6 +228,56 @@ def init_state(cfg: geometry.SimConfig, initial_pe=None,
     )
 
 
+# Under a vmapped lane axis XLA tiles a (lanes, n) leaf (8, 128) where it
+# picks the layout (scan carry, cond operands), but a batched scatter
+# linearises (lane, index) into one flat index and wants the leaf flat, so
+# every such scatter copies the whole leaf out and back. A (n/128, 128) leaf
+# tiled (8, 128) holds the bytes of a flat (n,) leaf tiled (1024,): carried
+# 128 wide, the scatter's flat operand is a bitcast, not a copy.
+FOLD = 128
+
+
+def foldable(x) -> bool:
+    """A rank-1 leaf of a whole number of (8, 128) tiles, more than one: the
+    per-slot and per-logical leaves, not the one-tile per-block leaves of a
+    1,024-block drive, whose copies cost nothing."""
+    n = x.shape[0] if x.ndim == 1 else 0
+    return n > 8 * FOLD and n % (8 * FOLD) == 0
+
+
+def fold_lanes(s):
+    """Every :func:`foldable` leaf of ``s`` reshaped to ``(-1, FOLD)``."""
+    with jax.named_scope("lane_fold"):
+        return jax.tree.map(
+            lambda x: x.reshape(-1, FOLD) if foldable(x) else x, s)
+
+
+def unfold_lanes(s, like):
+    """Inverse of :func:`fold_lanes`: each leaf back to its leaf of ``like``'s
+    shape (only the shapes of ``like`` are read)."""
+    with jax.named_scope("lane_fold"):
+        return jax.tree.map(lambda x, ref: x.reshape(ref.shape), s, like)
+
+
+def take(x, i, wide: bool):
+    """``x[i]``; ``wide`` reads a :func:`foldable` leaf through its 128-wide
+    view (see :func:`put`)."""
+    if wide and foldable(x):
+        return x.reshape(-1, FOLD)[i // FOLD, i % FOLD]
+    return x[i]
+
+
+def put(x, i, v, wide: bool):
+    """``x.at[i].set(v, mode="drop")``; ``wide`` scatters into a
+    :func:`foldable` leaf through its 128-wide view, whose layout under a
+    vmapped lane axis is the one the scatter wants, so XLA adds no relayout
+    copy around it. An index past the end stays dropped."""
+    if wide and foldable(x):
+        w = x.reshape(-1, FOLD).at[i // FOLD, i % FOLD].set(v, mode="drop")
+        return w.reshape(x.shape)
+    return x.at[i].set(v, mode="drop")
+
+
 def check_invariants(s: SSDState, cfg: geometry.SimConfig, where: str = "") -> None:
     """Assert full-state FTL consistency (host-side numpy; test helper).
 

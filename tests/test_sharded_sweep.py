@@ -16,7 +16,8 @@ import numpy as np
 import pytest
 
 from repro.experiments import registry, sweep
-from repro.ssdsim import engine, geometry, policies
+from repro.ssdsim import engine, ftl, geometry, policies
+from repro.ssdsim import state as st
 
 TINY = geometry.tiny_config()
 N_DEV = len(jax.devices())
@@ -155,7 +156,8 @@ def _gate_spec(obs_level="off"):
 
 
 def _lane_cfg(spec, run):
-    return dataclasses.replace(spec.base, initial_pe=run.initial_pe, r1=run.r1)
+    return dataclasses.replace(spec.base, initial_pe=run.initial_pe, r1=run.r1,
+                               r2_override=run.r2_override)
 
 
 def _own_run(spec, run):
@@ -211,6 +213,106 @@ class TestGatedPassesUnderVmap:
             m["run"] = r["run"]
             want.append(m)
         _assert_identical(res, want)
+
+
+# ------------- conversions under vmap (ftl.maybe_migrate_pages) -------------
+
+# a drive whose per-slot and per-logical leaves fold 128 wide (4,096 slots,
+# 3,072 logical pages) and whose conversions need four free blocks. In some
+# chunk the lanes disagree: the worn lane with the paper's R1 and R2 sits
+# below the free-block guard, the lane with the paper's R1 and an R2 no read
+# reaches converts, and the lane whose R1 and R2 no read reaches has no page
+# to convert
+MIG_CHUNKS = 24
+MIG_BASE = geometry.tiny_config(policy=geometry.RARO, n_logical=3072)
+MIG_VARIANTS = {
+    "plain": {},
+    # uncorrectable migration reads, rebuilds and the obs event ring
+    "faults_obs": dict(read_fail_rate=0.02, max_read_retries=3,
+                       parity_rebuild=True, obs_level="full"),
+}
+
+
+def _mig_spec(variant):
+    return sweep.SweepSpec(
+        scenario="hotspot_shift", n_requests=MIG_CHUNKS * MIG_BASE.chunk,
+        policies=(geometry.RARO,), initial_pe=(2500,), seeds=(0,),
+        r1=(1, 64), r2_override=(-1, 64),
+        base=dataclasses.replace(MIG_BASE, **MIG_VARIANTS[variant]),
+    )
+
+
+class TestMigrationUnderVmap:
+    @pytest.mark.parametrize("variant", sorted(MIG_VARIANTS))
+    @pytest.mark.parametrize("devices", [None, 1], ids=["vmap", "shard_map"])
+    def test_lanes_that_disagree_match_their_own_runs(self, devices, variant):
+        """Within one chunk some lanes convert pages, one has none to
+        convert and one sits below the free-block guard; every lane's
+        summary still equals its own ``engine.run`` bit for bit."""
+        spec = _mig_spec(variant)
+        runs = sweep.expand(spec)
+        guard = ftl._dest_unroll(spec.base, spec.base.migrate_pages_per_chunk) + 2
+        below, moved, want = {}, {}, []
+        for run in runs:
+            tr = registry.build(spec.scenario, spec.base, spec.n_requests,
+                                seed=run.seed)
+            s, m = engine.run(_lane_cfg(spec, run), tr)
+            knobs = (run.r1, run.r2_override)
+            # chunk k starts from chunk k - 1's free pool
+            below[knobs] = np.asarray(m.free_blocks)[:-1] < guard
+            moved[knobs] = np.diff(np.asarray(m.migrated)) > 0
+            want.append(engine.summarize(jax.device_get(s), _lane_cfg(spec, run)))
+        assert not moved[64, 64].any()
+        assert (below[1, -1] & ~moved[1, -1] & moved[1, 64]).any(), (below, moved)
+        res = sweep.run_sweep(spec, devices=devices)
+        for m, r in zip(want, res):
+            m["run"] = r["run"]
+            # a gated pass counts its chunks once for the whole batch
+            for d in (m, r):
+                d.pop("gated_pass_chunks", None)
+        _assert_identical(res, want)
+
+    def test_sweep_states_hold_the_ftl_invariants(self):
+        """Each lane's final state of that grid, unfolded, keeps the FTL's
+        mapping and accounting invariants."""
+        spec = _mig_spec("plain")
+        runs = sweep.expand(spec)
+        tr = registry.build(spec.scenario, spec.base, spec.n_requests, seed=0)
+        lpns = np.stack([tr["lpn"]] * len(runs)).astype(np.int32)
+        ops = np.stack([tr["op"]] * len(runs)).astype(np.int32)
+
+        def knob(name):
+            return np.asarray([getattr(r, name) for r in runs], np.int32)
+
+        knobs = policies.RunKnobs(r1=knob("r1"), r2_override=knob("r2_override"),
+                                  initial_pe=knob("initial_pe"))
+        states = jax.device_get(
+            sweep._sweep_jit(spec.base, lpns, ops, True, knobs))
+        for i, run in enumerate(runs):
+            st.check_invariants(sweep._take_run(states, i), _lane_cfg(spec, run),
+                                where=run.tag())
+
+
+@pytest.mark.parametrize("cfg", [MIG_BASE, geometry.SimConfig()],
+                         ids=["tiny", "table3"])
+def test_fold_lanes_round_trip(cfg):
+    """``fold_lanes`` folds exactly the per-slot and per-logical leaves 128
+    wide, and ``unfold_lanes`` gives every leaf back its shape, dtype and
+    values."""
+    s = jax.eval_shape(lambda: st.init_state(cfg))
+    folded = jax.eval_shape(st.fold_lanes, s)
+    wide = {n for n, a, b in zip(s._fields, s, folded) if a.shape != b.shape}
+    assert wide == {"l2p", "p2l", "page_write_ms", "heat"}
+    for n in wide:
+        assert getattr(folded, n).shape == (getattr(s, n).shape[0] // 128, 128)
+    back = jax.eval_shape(lambda f: st.unfold_lanes(f, s), folded)
+    for n, a, b in zip(s._fields, s, back):
+        assert (a.shape, a.dtype) == (b.shape, b.dtype), n
+    if cfg is MIG_BASE:
+        s0 = st.init_state(cfg)
+        s1 = st.unfold_lanes(st.fold_lanes(s0), s0)
+        for n, a, b in zip(s0._fields, s0, s1):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=n)
 
 
 def _eqns(jaxpr, conds=()):

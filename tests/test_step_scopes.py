@@ -110,3 +110,13 @@ def test_step_ops_carry_one_phase(kind, has_writes, metadata_in_cache_key):
     found = {p for name in traced for p in name.split("/") if p in PHASES}
     want = set(PHASES) if has_writes else set(PHASES) - {"step_write"}
     assert found == want
+
+
+@pytest.mark.parametrize("kind", ["run", "sweep"])
+def test_lane_fold_scope_only_in_the_batched_loop(kind, metadata_in_cache_key):
+    """The sweep carries its large state leaves 128 wide through the scan
+    and the batch-gated conds under the ``lane_fold`` scope; the single
+    drive folds nothing."""
+    names = {name for _, name in _loop_ops(_compiled(kind, True)) if name}
+    folded = {n for n in names if "/lane_fold/" in n}
+    assert bool(folded) == (kind == "sweep"), sorted(folded)[:5]

@@ -9,6 +9,7 @@ this file.
 """
 
 import os
+import re
 from pathlib import Path
 
 import jax
@@ -97,31 +98,31 @@ def test_sweep_sharded_compiles_on_four_chips(topo):
     _fits(compiled)
 
 
-def test_table3_age_grid_sweep_fits_one_chip(one_chip):
-    """The benchmark's twelve-lane Table III grid as ``run_sweep`` compiles
-    it on one chip, with its reclaim and GC passes behind the batch gate."""
-    cell = harness.find_cell("table3_age_grid.fio_zipf_read", REPO)
+def _grid_cell(name):
+    cell = harness.find_cell(name, REPO)
     cfg = harness.sim_config(cell.config)
-    assert (cfg.n_blocks, cfg.slots_per_block) == (1024, 1024)
     r = len(cell.config["initial_pe"]) * cell.config["seeds_per_stage"]
     n_chunks = -(-cell.config["requests_per_run"] // cfg.chunk)
+    return cell, cfg, r, n_chunks
+
+
+@pytest.fixture(scope="module")
+def age_grid(one_chip):
+    """The benchmark's twelve-lane Table III grid as ``run_sweep`` compiles
+    it on one chip: ``(cfg, lanes, compiled)``."""
+    _, cfg, r, n_chunks = _grid_cell("table3_age_grid.fio_zipf_read")
     x = jax.ShapeDtypeStruct((r, n_chunks, cfg.chunk), jnp.int32,
                              sharding=one_chip)
     k = jax.ShapeDtypeStruct((r,), jnp.int32, sharding=one_chip)
     knobs = policies.RunKnobs(r1=k, r2_override=k, initial_pe=k)
-    compiled = sweep._sweep_jit.lower(cfg, x, x, False, knobs).compile()
-    _fits(compiled)
-    assert " conditional(" in compiled.as_text()
+    return cfg, r, sweep._sweep_jit.lower(cfg, x, x, False, knobs).compile()
 
 
-def test_table3_age_grid_4chip_sweep_fits_each_chip(topo):
+@pytest.fixture(scope="module")
+def age_grid_4chip(topo):
     """The four-chip benchmark grid as ``run_sweep(devices=4)`` compiles it:
-    48 Table III lanes of 64 chunks split 12 a chip over a 2x2 mesh, each
-    chip's share within its memory, and no collective across chips."""
-    cell = harness.find_cell("table3_age_grid_4chip.fio_zipf_read", REPO)
-    cfg = harness.sim_config(cell.config)
-    r = len(cell.config["initial_pe"]) * cell.config["seeds_per_stage"]
-    n_chunks = -(-cell.config["requests_per_run"] // cfg.chunk)
+    ``(cfg, lanes a chip, compiled)``."""
+    cell, cfg, r, n_chunks = _grid_cell("table3_age_grid_4chip.fio_zipf_read")
     assert (cell.chips, r, n_chunks, cfg.chunk) == (4, 48, 64, 1024)
     mesh = Mesh(np.asarray(topo.devices[:cell.chips]), ("runs",))
     runs = NamedSharding(mesh, PartitionSpec("runs"))
@@ -130,11 +131,81 @@ def test_table3_age_grid_4chip_sweep_fits_each_chip(topo):
     knobs = policies.RunKnobs(r1=k, r2_override=k, initial_pe=k)
     compiled = sweep._sweep_sharded_jit.lower(
         cfg, x, x, False, knobs, None, mesh).compile()
+    return cfg, r // cell.chips, compiled
+
+
+def test_table3_age_grid_sweep_fits_one_chip(age_grid):
+    """The twelve-lane grid fits one chip, with its reclaim and GC passes
+    behind the batch gate."""
+    cfg, _, compiled = age_grid
+    assert (cfg.n_blocks, cfg.slots_per_block) == (1024, 1024)
+    _fits(compiled)
+    assert " conditional(" in compiled.as_text()
+
+
+def test_table3_age_grid_4chip_sweep_fits_each_chip(age_grid_4chip):
+    """48 Table III lanes of 64 chunks split 12 a chip over a 2x2 mesh, each
+    chip's share within its memory, and no collective across chips."""
+    _, _, compiled = age_grid_4chip
     _fits(compiled)
     text = compiled.as_text()
     assert " conditional(" in text
     for collective in ("all-reduce(", "all-gather(", "collective-permute(", "all-to-all("):
         assert collective not in text
+
+
+_CALLEES = re.compile(r"(?:body|condition|to_apply|branch_computations|calls)="
+                      r"\{?(%[\w.\-]+(?:, %[\w.\-]+)*)\}?")
+
+
+def _relayout_writes(hlo: str, shapes: tuple[str, ...]) -> list[str]:
+    """Names of the ``dynamic-update-slice``s into one of ``shapes`` that a
+    ``while`` body runs, outside the branches of the gated reclaim and GC
+    conditionals. Such a write, in a loop XLA made, is how a relayout copy
+    of a whole per-slot or per-logical leaf shows in a compiled program."""
+    comps, entry, cur = {}, None, None
+    for line in hlo.splitlines():
+        if line and not line.startswith(" ") and line.rstrip().endswith("{"):
+            cur = line.removeprefix("ENTRY ").split()[0].lstrip("%")
+            comps[cur] = []
+            entry = cur if line.startswith("ENTRY") else entry
+        elif line.startswith("  ") and cur is not None:
+            comps[cur].append(line.strip().removeprefix("ROOT "))
+    hits, seen, todo = [], set(), [(entry, False, False)]
+    while todo:
+        key = todo.pop()
+        if key in seen:
+            continue
+        seen.add(key)
+        comp, in_loop, gated = key
+        for instr in comps[comp]:
+            lhs, rhs = instr.split(" = ", 1)
+            opcode = re.search(r"\s([\w\-]+)\(", rhs)
+            op = opcode.group(1) if opcode else ""
+            if (op == "dynamic-update-slice" and in_loop and not gated
+                    and rhs.startswith(shapes)):
+                hits.append(lhs)
+            name = re.search(r'op_name="([^"]*)"', instr)
+            into_gate = op == "conditional" and name is not None and (
+                "step_reclaim" in name.group(1) or "step_gc" in name.group(1))
+            for callees in _CALLEES.findall(instr):
+                for c in callees.replace("%", "").split(", "):
+                    if c in comps:
+                        todo.append((c, in_loop or op == "while",
+                                     gated or into_gate))
+    return hits
+
+
+@pytest.mark.parametrize("grid", ["age_grid", "age_grid_4chip"])
+def test_table3_grid_scan_copies_no_state_leaf(grid, request):
+    """Carried 128 wide, the per-slot and per-logical leaves are scattered
+    into without the whole-leaf relayout loops XLA once put around every
+    conversion: no loop outside the gated reclaim and GC branches writes a
+    ``[1, lanes, n_slots]`` or ``[1, lanes, n_logical]`` leaf."""
+    cfg, lanes, compiled = request.getfixturevalue(grid)
+    shapes = tuple(f"{t}[1,{lanes},{n}]" for t in ("s32", "f32")
+                   for n in (cfg.n_slots, cfg.n_logical))
+    assert _relayout_writes(compiled.as_text(), shapes) == []
 
 
 def _tiered_args(one_chip):
